@@ -18,7 +18,7 @@ import subprocess
 import sys
 import time
 
-from benchmarks.util import row
+from benchmarks.util import cpu_child_env, row
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -27,9 +27,7 @@ def run(devices=(1, 2, 4, 8)) -> list[str]:
     out = []
     lls = {}
     for n in devices:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(REPO, "src")
-        env.pop("XLA_FLAGS", None)
+        env = cpu_child_env(REPO)
         t0 = time.time()
         res = subprocess.run(
             [sys.executable, "-m", "repro.launch.lda_dist_check",
@@ -45,7 +43,7 @@ def run(devices=(1, 2, 4, 8)) -> list[str]:
         lls[n] = rep["ll"][-1]
         out.append(row(
             f"fig5/nomad_{n}dev", wall * 1e6 / max(n_swept, 1),
-            f"final_ll={rep['ll'][-1]:.0f};imbalance="
+            f"platform=cpu;final_ll={rep['ll'][-1]:.0f};imbalance="
             f"{rep['round_imbalance']:.2f};exact="
             f"{rep['n_td_mismatch'] + rep['n_wt_mismatch'] == 0}"))
     if len(lls) > 1:

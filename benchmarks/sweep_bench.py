@@ -68,7 +68,7 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from benchmarks.util import row, time_fn
+from benchmarks.util import cpu_child_env, row, time_fn
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_JSON = os.path.join(REPO, "BENCH_sweep.json")
@@ -210,9 +210,7 @@ def _recovery_entry(W: int, fast: bool = False) -> dict:
     previous valid slot, finish — back-to-back, so ``overhead_ratio``
     cancels host speed the way the padding canary's interleaved
     measurement does."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("XLA_FLAGS", None)
+    env = cpu_child_env(REPO)
     sweeps, kill_at = (4, 2) if fast else (6, 3)
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.chaos_check",
@@ -223,7 +221,8 @@ def _recovery_entry(W: int, fast: bool = False) -> dict:
         raise RuntimeError(f"chaos_check recovery W={W}: "
                            + res.stderr[-500:])
     rep = json.loads(res.stdout.strip().splitlines()[-1])
-    return {"path": "recovery", "W": W, "sweeps": rep["sweeps"],
+    return {"path": "recovery", "platform": "cpu", "W": W,
+            "sweeps": rep["sweeps"],
             "kill_at": rep["kill_at"],
             "straight_sec": rep["straight_sec"],
             "recovery_sec": rep["recovery_sec"],
@@ -234,9 +233,7 @@ def _recovery_entry(W: int, fast: bool = False) -> dict:
 
 def _nomad_entries(W: int, fast: bool = False) -> list[dict]:
     entries = []
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("XLA_FLAGS", None)
+    env = cpu_child_env(REPO)
 
     def one(inner_mode: str, B: int, ring_mode: str, layout: str,
             doc_tile: int = 0, r_mode: str = "dense") -> dict:
@@ -252,7 +249,8 @@ def _nomad_entries(W: int, fast: bool = False) -> list[dict]:
                 + res.stderr[-500:])
         rep = json.loads(res.stdout.strip().splitlines()[-1])
         return {
-            "path": "nomad", "backend": inner_mode, "B": B,
+            "path": "nomad", "platform": "cpu", "backend": inner_mode,
+            "B": B,
             "W": W, "ring_mode": ring_mode, "layout": layout,
             "r_mode": r_mode, "r_cap": rep["r_cap"],
             "T": 16, "k": rep["blocks_per_worker"],
@@ -416,16 +414,14 @@ def check_regression(threshold: float | None = None) -> list[str]:
 def _canary_entry(W: int) -> dict:
     """Run the interleaved B=W vs B=4W ragged-fused canary measurement
     (``repro.launch.lda_canary_check``) and return its bench entry."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("XLA_FLAGS", None)
+    env = cpu_child_env(REPO)
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.lda_canary_check", str(W)],
         capture_output=True, text=True, env=env, timeout=900)
     if res.returncode != 0:
         raise RuntimeError(f"lda_canary_check W={W}: " + res.stderr[-500:])
     rep = json.loads(res.stdout.strip().splitlines()[-1])
-    return {"path": "canary", "W": W,
+    return {"path": "canary", "platform": "cpu", "W": W,
             "tokens_per_sec_w": rep["tokens_per_sec_w"],
             "tokens_per_sec_4w": rep["tokens_per_sec_4w"],
             "ratio_4w_over_w": rep["ratio_4w_over_w"]}

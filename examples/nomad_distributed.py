@@ -31,6 +31,10 @@ import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            + os.environ.get("XLA_FLAGS", ""))
 
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
+
 import time  # noqa: E402
 
 import jax   # noqa: E402

@@ -1,29 +1,16 @@
-"""Version compatibility shims for the jax API surface we depend on.
+"""Version spellings of the jax API surface this repo depends on.
 
-The repo targets the modern ``jax.shard_map`` entry point (keyword
-``check_vma``); older jax releases only ship
-``jax.experimental.shard_map.shard_map`` (keyword ``check_rep``).  Both are
-the same SPMD primitive — only the import path and the replication-check
-keyword differ — so every internal user imports :func:`shard_map` from here.
-The keyword is resolved by signature inspection, not import path: transition
-releases exposed ``jax.shard_map`` while still spelling it ``check_rep``.
+Every internal user imports :func:`shard_map` from here, so a future
+rename of the entry point or of its replication-check keyword is a
+one-line change.
 """
 from __future__ import annotations
 
-import inspect
+from jax import shard_map as _shard_map
 
 __all__ = ["shard_map"]
-
-try:
-    from jax import shard_map as _shard_map           # jax >= 0.6
-except ImportError:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+                      check_vma=check_vma)

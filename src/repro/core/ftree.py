@@ -74,6 +74,9 @@ def build(p: jax.Array) -> jax.Array:
 
     Works on the last axis; leading axes are batch.  Θ(T) work, built level by
     level with pairwise sums (vectorized — the paper's reverse-index loop).
+    Each node is the single add ``left + right`` of its children, spelt as
+    an elementwise add so no compiler can merge levels into a wider
+    reduction — the Pallas kernels rebuild the same tree node by node.
     """
     T = p.shape[-1]
     if not _is_pow2(T):
@@ -82,7 +85,7 @@ def build(p: jax.Array) -> jax.Array:
     levels = [p]
     cur = p
     while cur.shape[-1] > 1:
-        cur = cur.reshape(*cur.shape[:-1], cur.shape[-1] // 2, 2).sum(-1)
+        cur = cur[..., 0::2] + cur[..., 1::2]
         levels.append(cur)
     zero = jnp.zeros_like(p[..., :1])
     return jnp.concatenate([zero] + levels[::-1], axis=-1)

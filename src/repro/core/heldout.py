@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core.prefix import prefix_sum
 from repro.core.samplers import lsearch_guarded
 from repro.data.corpus import Corpus
 
@@ -152,8 +153,8 @@ def _fold_in_core(word_ids, doc_ids, pos, phi, alpha, key, *,
             d, w, t_old = doc_ids[i], word_ids[i], z[i]
             n_td = n_td.at[d, t_old].add(-1)
             p = (n_td[d].astype(jnp.float32) + alpha) * phi[w]
-            cdf = jnp.cumsum(p)
-            # Guarded LSearch: u01·cdf[-1] shares the cumsum reduction, so
+            cdf = prefix_sum(p)
+            # Guarded LSearch: u01·cdf[-1] shares the prefix sum, so
             # overrun needs u01·M to round up to M — impossible for
             # u01 ≤ 1−2⁻²⁴ f32 — but the guard also covers all-zero φ
             # rows, where a clip would silently select topic T−1 with
@@ -243,7 +244,7 @@ def fold_in_batch(word_ids, valid, phi, alpha, doc_keys, sweeps: int = 20):
                 w, t_old = words[i], z[i]
                 n_td = n_td.at[t_old].add(-vi)
                 p = (n_td.astype(jnp.float32) + alpha) * phi[w]
-                cdf = jnp.cumsum(p)
+                cdf = prefix_sum(p)
                 t_new = lsearch_guarded(cdf, u01 * cdf[-1])
                 t_new = jnp.where(vi > 0, t_new, t_old)
                 n_td = n_td.at[t_new].add(vi)

@@ -220,8 +220,7 @@ def _queue_sweep_fused(tok_doc, tok_wrd, tok_valid, tok_bound, z_q,
                        cell_start: int = 0, num_cells: int | None = None,
                        dto=None, doc_rows: int = 0, doc_blk: int = 0,
                        r_mode: str = "dense", r_cap: int = 0,
-                       topics=None, counts=None,
-                       interpret: bool = True):
+                       topics=None, counts=None, *, interpret: bool):
     """Exact per-token chain like :func:`_cell_sweep`, but the worker's whole
     per-round block queue runs as ONE fused ``pallas_call``
     (:func:`repro.kernels.fused_sweep.fused_sweep_cells`): grid over the k
@@ -313,7 +312,7 @@ def _queue_sweep_ragged_fused(tok_doc, tok_wrd, tok_valid, tok_bound, z_s,
                               dto=None, doc_rows: int = 0,
                               r_mode: str = "dense", r_cap: int = 0,
                               topics=None, counts=None,
-                              interpret: bool = True):
+                              interpret: bool):
     """The ragged nomad hot path: the worker's whole per-round stream as
     ONE flat-grid ``pallas_call`` with scalar-prefetch block paging
     (:func:`repro.kernels.fused_sweep.fused_sweep_ragged`).  Bit-exact
@@ -378,8 +377,9 @@ def _queue_sweep_ragged_vectorized(tok_doc, tok_wrd, tok_valid, tok_bound,
     nc = k_total - cell_start if num_cells is None else int(num_cells)
     lo, hi = tile_start * tile, (tile_start + nt_) * tile
     sub = lambda a: a[lo:hi]
-    cell_tok = jnp.repeat(cot[tile_start:tile_start + nt_] - cell_start,
-                          tile, total_repeat_length=nt_ * tile)
+    cell_tok = jnp.broadcast_to(
+        (cot[tile_start:tile_start + nt_] - cell_start)[:, None],
+        (nt_, tile)).reshape(nt_ * tile)
     doc_seg, valid_seg = sub(tok_doc), sub(tok_valid)
     wrd_flat = cell_tok * J + sub(tok_wrd)
     u_seg = sub(u)
@@ -609,7 +609,11 @@ def nomad_sweep_fn(mesh: Mesh, ring_axes: Sequence[str], *,
                       chunk(tok_bound))
                 z_q_in = chunk(z)
                 cot = chunk(cell_of_tile)                      # (n_tiles,)
-                cell_tok = jnp.repeat(cot, tile, total_repeat_length=S)
+                # every tile holds `tile` tokens: a broadcast, not a
+                # jnp.repeat (whose total_repeat_length form the TPU
+                # compiler takes minutes over at real stream lengths)
+                cell_tok = jnp.broadcast_to(
+                    cot[:, None], (cot.shape[0], tile)).reshape(S)
                 uid = (b0 + cell_tok) * L + chunk(tok_slot)
                 u = _token_uniforms(key_r, uid)
                 sweep_args = tq + (z_q_in, n_td[0], n_wt_q, n_t_local, u,

@@ -217,20 +217,27 @@ def _order_bins_for_halves(bins: np.ndarray, weights: np.ndarray,
 def lpt_assign(weights: np.ndarray, n_bins: int,
                balance: bool = True) -> np.ndarray:
     """Assign items to bins. ``balance=True``: greedy LPT (largest first to
-    lightest bin); else contiguous equal-count chunks (the naive split)."""
+    lightest bin, ties to the bin holding fewest items); else contiguous
+    equal-count chunks (the naive split).
+
+    The item-count tie-break matters for zero-weight items — vocabulary
+    words absent from the corpus: they never change a bin's load, so
+    without it every one of them lands in the same bin, and that bin's
+    row count (a word-topic block's ``J``, which the fused kernel holds
+    in VMEM) grows with the vocabulary."""
     n = weights.shape[0]
     if not balance:
         return (np.arange(n) * n_bins // max(n, 1)).astype(np.int32)
     import heapq
     order = np.argsort(-weights, kind="stable")
     out = np.zeros(n, dtype=np.int32)
-    # LPT via a min-heap keyed on bin load: pop lightest, assign, push back.
-    heap = [(0, b) for b in range(n_bins)]
+    # LPT via a min-heap keyed on (load, items): pop lightest, assign, push.
+    heap = [(0, 0, b) for b in range(n_bins)]
     heapq.heapify(heap)
     for i in order:
-        load, b = heapq.heappop(heap)
+        load, items, b = heapq.heappop(heap)
         out[i] = b
-        heapq.heappush(heap, (load + int(weights[i]), b))
+        heapq.heappush(heap, (load + int(weights[i]), items + 1, b))
     return out
 
 

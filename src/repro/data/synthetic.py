@@ -53,17 +53,42 @@ def make_corpus(
 
 def _sample_rows(rng: np.random.Generator, table: np.ndarray,
                  rows: np.ndarray) -> np.ndarray:
-    """Draw one categorical sample from ``table[rows[k]]`` for each k."""
+    """Draw one categorical sample from ``table[rows[k]]`` for each k.
+
+    Token ``k`` takes ``#{j : cdf[rows[k], j] + k ≤ u_k + k}`` (float64
+    sums) — the inverse CDF of its row with both sides offset by ``k``,
+    the form a single ``searchsorted`` over all rows laid end to end
+    needs.  Tokens are grouped by row so memory stays ``O(table + N)``
+    at any corpus size (the end-to-end layout would be ``N × cols``).
+    """
     cdf = np.cumsum(table, axis=1)
     cdf /= cdf[:, -1:]
-    u = rng.random(rows.shape[0])
-    # searchsorted per row via the "offset trick": each row's cdf is in [0,1];
-    # add the row index so rows occupy disjoint unit intervals.
-    flat = (cdf[rows] + np.arange(rows.shape[0])[:, None]).ravel()
-    targets = u + np.arange(rows.shape[0])
-    idx = np.searchsorted(flat, targets, side="right")
-    # flat position = k * T + idx_within_row
-    return (idx - np.arange(rows.shape[0]) * table.shape[1]).astype(np.int32)
+    n, cols = rows.shape[0], table.shape[1]
+    u = rng.random(n)
+    k = np.arange(n, dtype=np.float64)
+    target = u + k
+    out = np.empty(n, np.int64)
+    order = np.argsort(rows, kind="stable")
+    bounds = np.searchsorted(rows[order], np.arange(table.shape[0] + 1))
+    for r in range(table.shape[0]):
+        idx = order[bounds[r]:bounds[r + 1]]
+        if idx.size == 0:
+            continue
+        c, kk, tt = cdf[r], k[idx], target[idx]
+        j = np.searchsorted(c, u[idx], side="right")
+        # The offset sums round: settle j on the exact offset count.
+        while True:
+            up = (j < cols) & (c[np.minimum(j, cols - 1)] + kk <= tt)
+            if not up.any():
+                break
+            j += up
+        while True:
+            down = (j > 0) & (c[np.maximum(j - 1, 0)] + kk > tt)
+            if not down.any():
+                break
+            j -= down
+        out[idx] = np.minimum(j, cols - 1)
+    return out.astype(np.int32)
 
 
 class SyntheticCorpusSpec:

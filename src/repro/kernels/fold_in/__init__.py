@@ -6,5 +6,6 @@ Same package shape as ``kernels/fused_sweep``:
     ref.py     — pure-jnp oracle on the same precomputed draws
 """
 from repro.kernels.fold_in.ops import (fold_in_draws,  # noqa: F401
-                                       fold_in_fused, fold_in_vmem_bytes)
+                                       fold_in_fused, fold_in_smem_bytes,
+                                       fold_in_vmem_bytes)
 from repro.kernels.fold_in.ref import fold_in_kernel_ref  # noqa: F401

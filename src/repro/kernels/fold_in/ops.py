@@ -17,20 +17,28 @@ import jax.numpy as jnp
 
 from repro.core.heldout import _ROLE_INIT, _ROLE_SWEEP
 from repro.kernels.fold_in.fold_in import fold_in_pallas
-from repro.kernels.fused_sweep.ops import (VMEM_BUDGET_BYTES,
-                                           default_interpret)
+from repro.kernels.fused_sweep.ops import (SMEM_BUDGET_BYTES,
+                                           VMEM_SCOPED_DEFAULT_BYTES,
+                                           default_interpret,
+                                           topic_row_bytes)
 
 
 def fold_in_vmem_bytes(L: int, T: int, sweeps: int) -> int:
-    """VMEM-resident bytes of one fold-in kernel program (DESIGN.md §10a).
+    """VMEM bytes of one compiled fold-in kernel program (DESIGN.md §10a):
+    the i32 count output tile (double-buffered), the count scratch and
+    the f32 φ-row scratch — four topic rows.  φ stays in HBM — only one
+    row is ever resident — and the token streams live in SMEM
+    (:func:`fold_in_smem_bytes`), so neither ``L`` nor ``sweeps``
+    enters."""
+    del L, sweeps
+    return 4 * topic_row_bytes(T)
 
-    Per grid step: three i32 ``(1, L)`` token streams (words, mask, z0),
-    the f32 ``(1, sweeps·L)`` uniform block, the i32 ``(1, T)`` count
-    output, the f32 ``(1, T)`` φ-row scratch, and the loop-carried
-    ``z``/``n_td`` values (≈ one more L + T).  φ itself stays in HBM —
-    only one row is ever resident.
-    """
-    return 4 * (3 * L + sweeps * L + 2 * T) + 4 * (L + T)
+
+def fold_in_smem_bytes(L: int) -> int:
+    """SMEM bytes of one compiled fold-in kernel program: the words, mask
+    and ``z0`` rows plus one sweep's uniforms, each double-buffered, and
+    the ``(L,)`` assignment scratch."""
+    return 4 * (2 * 4 * L + L)
 
 
 def fold_in_draws(doc_keys, L: int, T: int, sweeps: int):
@@ -94,15 +102,16 @@ def fold_in_fused(word_ids, valid, phi, alpha, doc_keys,
         interpret = default_interpret()
     if not interpret:
         vmem = fold_in_vmem_bytes(L, T, int(sweeps))
-        if vmem > VMEM_BUDGET_BYTES:
+        smem = fold_in_smem_bytes(L)
+        if vmem > VMEM_SCOPED_DEFAULT_BYTES or smem > SMEM_BUDGET_BYTES:
             raise ValueError(
-                f"fold-in kernel state ({vmem / 2**20:.1f} MiB) exceeds "
-                f"the VMEM budget; lower the length bucket L={L} / "
-                f"sweeps={sweeps} or use inner_mode='scan'")
+                f"fold-in kernel state ({vmem / 2**20:.1f} MiB VMEM, "
+                f"{smem / 2**10:.0f} KiB SMEM) exceeds the VMEM/SMEM "
+                f"budget; lower the length bucket L={L} or use "
+                f"inner_mode='scan'")
     z0, u = fold_in_draws(doc_keys, L, T, int(sweeps))
     return fold_in_pallas(
-        word_ids.astype(jnp.int32), valid.astype(jnp.int32), z0,
-        u.reshape(D, int(sweeps) * L),
+        word_ids.astype(jnp.int32), valid.astype(jnp.int32), z0, u,
         jnp.asarray(alpha, jnp.float32).reshape(1, 1),
         phi.astype(jnp.float32), sweeps=int(sweeps),
         interpret=bool(interpret))

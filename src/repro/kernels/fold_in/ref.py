@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.prefix import prefix_sum
 from repro.core.samplers import lsearch_guarded
 
 
@@ -25,7 +26,7 @@ def fold_in_kernel_ref(word_ids, valid, z0, u, alpha, phi):
 
     ``word_ids``/``valid``/``z0``: (D, L); ``u``: (D, sweeps, L) f32;
     returns (D, T) i32 counts — same contract as ``fold_in_pallas``
-    (which takes ``u`` flattened to ``(D, sweeps·L)``).
+    (which takes the same ``(D, sweeps, L)`` uniforms).
     """
     T = phi.shape[1]
     L = word_ids.shape[1]
@@ -44,7 +45,7 @@ def fold_in_kernel_ref(word_ids, valid, z0, u, alpha, phi):
                 w, t_old = words[i], z[i]
                 n_td = n_td.at[t_old].add(-vi)
                 p = (n_td.astype(jnp.float32) + alpha) * phi[w]
-                cdf = jnp.cumsum(p)
+                cdf = prefix_sum(p)
                 t_new = lsearch_guarded(cdf, u01 * cdf[-1])
                 t_new = jnp.where(vi > 0, t_new, t_old)
                 n_td = n_td.at[t_new].add(vi)

@@ -39,7 +39,7 @@ def _kernel(depth: int, f_ref, u_ref, z_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ftree_sample_pallas(F: jax.Array, u01: jax.Array,
-                        *, interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     """z[k] = F.sample(u01[k]); F: (2T,) f32, u01: (N,) f32, N % N_BLK == 0."""
     two_t = F.shape[0]
     T = two_t // 2

@@ -33,7 +33,7 @@ def _kernel(depth: int, f_ref, t_ref, d_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ftree_update_pallas(F: jax.Array, ts: jax.Array, deltas: jax.Array,
-                        *, interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     two_t = F.shape[0]
     T = two_t // 2
     depth = T.bit_length() - 1

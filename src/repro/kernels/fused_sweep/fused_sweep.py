@@ -4,55 +4,55 @@ The ``lax.scan`` sweeps in :mod:`repro.core.cgs` and :mod:`repro.core.nomad`
 honour the exact Gibbs chain but pay for it in memory traffic: every token
 re-reads and re-writes its count rows and the F+tree through HBM, and each
 scan step is its own XLA while-loop iteration.  This kernel fuses the whole
-word-by-word sweep (decrement → F.update → q/r two-level draw → increment →
-F.update) into **one** ``pallas_call``:
+word-by-word sweep (decrement → F.update → q/r two-level draw →
+increment → F.update) into **one** ``pallas_call``.  Each piece of state
+lives where the TPU can address it the way the chain needs:
 
-* the F+tree (``2T`` f32) and the global topic counts ``n_t`` (``T`` i32)
-  stay VMEM-resident for the entire sweep — they are carried through the
-  per-token ``fori_loop`` as register/VMEM values and only written back to
-  the output buffers once per token tile;
-* the doc-topic table ``n_td`` and the word-topic block ``n_wt`` live in
-  VMEM buffers for the whole call; per token the kernel touches exactly one
-  row of each via dynamic-slice load/store (``pl.ds``) — no (N, T) HBM
-  intermediates are ever materialized;
+* the token streams (doc, word, valid, boundary, ``z``, uniform) are read
+  one scalar at a time, so their ``(n_blk,)`` tiles sit in **SMEM**, and
+  ``z'`` is written back there;
+* the F+tree walk and path update are scalar loops, so the tree sits in
+  SMEM scratch — internal nodes ``1..T−1`` in one ``(T,)`` buffer, the
+  leaves in a ``(1, T)`` buffer — with a VMEM ``(1, T)`` mirror of the
+  leaves for the vector side of the draw.  A word-boundary rebuild writes
+  the q vector to the mirror, DMAs it to the SMEM leaves and sums the
+  internal nodes bottom-up;
+* the doc-topic table ``n_td``, the word-topic block ``n_wt`` and ``n_t``
+  live in VMEM for the whole call; per token the kernel loads and stores
+  exactly one ``(1, T)`` row of each table by dynamic sublane index
+  (``pl.ds``) and updates lanes with iota-masked selects.  A scalar is
+  pulled out of a row by a masked sum, exact because one term is nonzero;
 * tokens are tiled over a sequential grid (``N_BLK`` per program).  The
-  count/tree outputs use constant index maps, so the state persists across
-  grid steps — the standard Pallas accumulator pattern — and the chain is
-  exact across tile boundaries.
+  count outputs use constant index maps, so the state persists across
+  grid steps — the standard Pallas accumulator pattern — and the tree
+  persists in scratch, so the chain is exact across tile boundaries.
 
-Two entry points share the tile body:
+Three grids share the tile body:
 
 * :func:`fused_sweep_pallas` — one token stream against one word-topic
   block (the serial ``cgs`` hot path).  Grid ``(n_tiles,)``.
 * :func:`fused_sweep_cells_pallas` — a *batch of k cells* (one nomad
   worker's whole per-round block queue) in a single call.  Grid
   ``(k, n_tiles)`` with the cell index leftmost, so the k cells run in
-  sequence on the sequential TPU grid; ``n_td``/``n_t``/``F`` use constant
-  index maps and carry across cell boundaries, while the per-cell
-  word-topic block ``n_wt[c]`` is paged in/out by the BlockSpec index map —
-  only one ``(J, T)`` block is VMEM-resident at a time.  Cross-cell chain
-  exactness needs no special handling: a cell's first valid token is always
-  a word boundary (``NomadLayout.tok_bound``), which rebuilds the tree from
-  the incoming block's q vector.  The same property makes the grid freely
-  *splittable*: a call over a sub-queue of ``m ≤ k`` cells (grid
-  ``(m, tiles)``, see ``ops.fused_sweep_cells``'s ``cell_start`` /
-  ``num_cells``) chains bit-identically with the calls for the remaining
-  cells — the pipelined nomad ring sweeps half-queues this way.
-
+  sequence on the sequential TPU grid; ``n_td``/``n_t``/F carry across
+  cell boundaries, while the per-cell word-topic block ``n_wt[c]`` is
+  paged in/out by the BlockSpec index map — only one ``(J, T)`` block is
+  VMEM-resident at a time.  Cross-cell chain exactness needs no special
+  handling: a cell's first valid token is always a word boundary
+  (``NomadLayout.tok_bound``), which rebuilds the tree from the incoming
+  block's q vector.  The same property makes the grid freely
+  *splittable*: a call over a sub-queue of ``m ≤ k`` cells chains
+  bit-identically with the calls for the remaining cells — the pipelined
+  nomad ring sweeps half-queues this way.
 * :func:`fused_sweep_ragged_pallas` — the same k-cell queue as a **ragged
-  tile stream** (``NomadLayout`` ``kind="ragged"``): the dense ``(k, L)``
-  grid pads every cell to the heaviest one, so the grid's token capacity
-  blows up with ``B``; the ragged stream pads each cell only to its next
-  tile multiple and the grid flattens to ``(n_tiles,)``.  The per-tile
-  cell id rides in as a **scalar-prefetch** operand
+  tile stream** (``NomadLayout`` ``kind="ragged"``): each cell is padded
+  only to its next tile multiple and the grid flattens to ``(n_tiles,)``.
+  The per-tile cell id rides in as a **scalar-prefetch** operand
   (``pltpu.PrefetchScalarGridSpec``): the ``n_wt`` BlockSpec index map
   reads ``cell_of_tile[t]`` to page the right ``(J, T)`` block, and the
-  kernel body compares ``cell_of_tile[t]`` against ``t−1``'s to detect
-  cell starts (the map is non-decreasing, so each block is paged in/out
-  exactly once).  Everything else — carried ``n_td``/``n_t``/``F``,
-  boundary rebuilds, masked no-op padding, splittability by tile range —
-  is identical to the cell-batch grid, and the chain is bit-equal to it
-  token for token.
+  body compares ``cell_of_tile[t]`` against ``t−1``'s to detect cell
+  starts (the map is non-decreasing, so each block is paged exactly
+  once).  The chain is bit-equal to the cell-batch grid token for token.
 
 Every entry point also has a **doc-tiled** twin (``*_docs_pallas``) that
 lifts the whole-shard VMEM residency of the doc-topic table: ``n_td``
@@ -63,8 +63,8 @@ with ``doc_tile``, whose grouped token order guarantees each grid step
 touches exactly one slab).  Slabs *recur* across cells, so BlockSpec
 window paging cannot carry them (an input window re-fetch reads the
 stale initial table; a revisited output window is undefined on TPU) —
-instead the kernel bulk-copies the table input→output once at the first
-step and then DMAs slabs in/out of the output buffer explicitly
+instead the table's HBM input is aliased to its output and the kernel
+DMAs slabs in/out of that one buffer explicitly
 (``pltpu.make_async_copy``): every page-in reads the accumulated counts
 because every write-back went through the same buffer.  The token chain
 itself is untouched — tiled and untiled execution over the same layout
@@ -77,27 +77,30 @@ rebuilds the tree from the incoming word's q vector; the tree starts zeroed,
 so the first valid token of the stream must be a boundary (guaranteed by
 ``Corpus.word_boundary`` and by ``NomadLayout.tok_bound``).
 
-Chain exactness: every float op (q rebuild, path update, cumsum, draw) is
-performed by the same :mod:`repro.core.ftree` value ops and in the same
-order as ``cgs.sweep_fplda_word``, so given identical uniforms the kernel
-reproduces that sweep's ``z``/counts bit-for-bit (the clip/max guards are
-no-ops on consistent count tables).  ``interpret=True`` is the CPU-safe
-default; the compiled path targets the layout above.
+Chain exactness: every float op (q rebuild, path update, prefix sum, draw)
+is the same op on the same operands, in the same order, as the scan oracle
+``ref.fused_sweep_ref``: the tree walk and path adds are the scalar ops of
+:mod:`repro.core.ftree`, the prefix sum is :func:`repro.core.prefix.prefix_sum`
+with a roll-based shift, and the r-vector is compacted by moving values
+only (:func:`repro.kernels.fused_sweep.rbucket.pack`).  Given identical
+uniforms the kernel reproduces the oracle's ``z``/counts/tree bit for bit,
+interpreted on the CPU and compiled on the TPU alike.  Every entry point
+takes ``interpret`` as a required keyword; ``ops.default_interpret`` is the
+one place that picks it.
 
 The r-bucket draw is **doc-sparse** (paper §3's |T_d| ≪ T argument,
-DESIGN.md §7): the r-term cumsum runs over the capacity-``r_cap``
+DESIGN.md §7): the r-term prefix sum runs over the capacity-``r_cap``
 compacted vector of the document's nonzero topics
 (:mod:`repro.kernels.fused_sweep.rbucket`).  Every kernel takes a static
-``r_cap`` and a ``sparse`` switch: dense mode recomputes the compaction
-from the VMEM ``n_td`` row per token (Θ(T)); sparse mode maintains it as
-per-doc ``(topics, counts)`` side tables — two extra ``(I, r_cap)`` i32
+``r_cap`` and a ``sparse`` switch: dense mode compacts the VMEM ``n_td``
+row per token (Θ(T log T) lane work); sparse mode maintains the compaction
+as per-doc ``(topics, counts)`` side tables — two extra ``(I, r_cap)`` i32
 operands riding in/out exactly like ``n_td`` (whole-VMEM with constant
-index maps, *including* in the doc-tiled twins: the tables are a factor
-``T/r_cap`` smaller than the table the slab paging evicts) — making the
-per-token r-draw Θ(r_cap), independent of T.  Both modes draw from the
-same compacted vector, so their chains are bit-identical (the rbucket
-module docstring carries the exactness argument; ``r_cap`` itself is
-chain-affecting, so compared runs must share it).
+index maps, *including* in the doc-tiled twins) — making the per-token
+r-draw Θ(r_cap).  Both modes draw from the same compacted vector, so their
+chains are bit-identical.  Sparse mode gathers ``q[topics]`` by value,
+which Mosaic does not lower: it runs interpreted only, and a compiled
+sparse call fails in the TPU compiler.
 """
 from __future__ import annotations
 
@@ -109,6 +112,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ftree
+from repro.core.prefix import (prefix_sum_tiles, topic_iota, topic_tile,
+                               tpu_roll)
 from repro.kernels.fused_sweep import rbucket
 
 N_BLK = 256  # tokens per grid program
@@ -116,80 +121,192 @@ N_BLK = 256  # tokens per grid program
 F32 = jnp.float32
 
 
+# ---------------------------------------------------------------------------
+# The F+tree in SMEM: internal nodes f_int[1..T-1] (f_int[0] stays 0), leaves
+# f_leaf[(R, C) tile of t] — heap node T + t — plus a VMEM (R, C) mirror of
+# the leaves.
+# ---------------------------------------------------------------------------
+class _Tree:
+    def __init__(self, T, f_int, f_leaf, mirror, sem):
+        self.T, self.d = T, ftree.depth(T)
+        self.R, self.C = topic_tile(T)
+        self.lc = self.C.bit_length() - 1
+        self.f_int, self.f_leaf, self.mirror, self.sem = (f_int, f_leaf,
+                                                          mirror, sem)
+
+    def _leaf(self, t):
+        """SMEM index of leaf ``t`` in the ``(R, C)`` leaf tile."""
+        return t >> self.lc, t & (self.C - 1)
+
+    def zero(self):
+        def body(i, c):
+            self.f_int[i] = jnp.float32(0.0)
+            self.f_leaf[self._leaf(i)] = jnp.float32(0.0)
+            return c
+        jax.lax.fori_loop(0, self.T, body, 0)
+        self.mirror[...] = jnp.zeros((self.R, self.C), F32)
+
+    def build(self, q):
+        """``ftree.build``: leaves = q, node i = node 2i + node 2i+1."""
+        T, f_int, f_leaf = self.T, self.f_int, self.f_leaf
+        self.mirror[...] = q
+        cp = pltpu.make_async_copy(self.mirror, f_leaf, self.sem)
+        cp.start()
+        cp.wait()
+
+        def from_leaves(j, c):
+            i = T - 1 - j                       # nodes T/2..T-1, descending
+            f_int[i] = (f_leaf[self._leaf(2 * i - T)]
+                        + f_leaf[self._leaf(2 * i + 1 - T)])
+            return c
+
+        def from_nodes(j, c):
+            i = T // 2 - 1 - j                  # nodes T/2-1..1, descending
+            f_int[i] = f_int[2 * i] + f_int[2 * i + 1]
+            return c
+
+        jax.lax.fori_loop(0, T // 2, from_leaves, 0)
+        jax.lax.fori_loop(0, T // 2 - 1, from_nodes, 0)
+
+    def leaf(self, t):
+        return self.f_leaf[self._leaf(t)]
+
+    def total(self):
+        return self.f_int[1] if self.T > 1 else self.f_leaf[0, 0]
+
+    def set_leaf(self, t, value, flat):
+        """``ftree.set_leaf``: add ``value − leaf`` along the leaf's path,
+        then mirror the new leaf into the VMEM tile."""
+        cur = self.leaf(t)
+        delta = value - cur
+        new = cur + delta
+        self.f_leaf[self._leaf(t)] = new
+        node = (t + self.T) >> 1
+        for _ in range(self.d):
+            self.f_int[node] = self.f_int[node] + delta
+            node = node >> 1
+        self.mirror[...] = jnp.where(flat == t, new, self.mirror[...])
+
+    def sample(self, u01):
+        """``ftree.sample``: the guarded top-down walk, one scalar per
+        level."""
+        T, f_int = self.T, self.f_int
+        u = u01 * self.total()
+        i = jnp.int32(1)
+        for lvl in range(self.d):
+            c = 2 * i
+            if lvl < self.d - 1:
+                left, right = f_int[c], f_int[c + 1]
+            else:
+                left, right = self.leaf(c - T), self.leaf(c + 1 - T)
+            go_right = (u >= left) & (right > 0)
+            i = c + go_right.astype(jnp.int32)
+            u = jnp.where(go_right, u - left, u)
+        return i - T
+
+    def flush(self, out_int, out_leaf):
+        for src, dst in ((self.f_int, out_int), (self.f_leaf, out_leaf)):
+            cp = pltpu.make_async_copy(src, dst, self.sem)
+            cp.start()
+            cp.wait()
+
+
+def _pick(tile, t):
+    """Entry ``t`` of an ``(R, C)`` topic tile as a scalar (masked sum:
+    exact, one nonzero term)."""
+    return jnp.sum(jnp.where(topic_iota(tile.shape) == t, tile,
+                             jnp.zeros_like(tile)))
+
+
+def _dense_r_draw(ntd_row, q, cap):
+    """Dense r-mode on an ``(R, C)`` doc-row tile: the compacted prefix
+    ``c`` (valid below ``cap``), the active count and each topic's rank —
+    :func:`rbucket.compact_row` + :func:`rbucket.r_cumsum` spelt in lane
+    moves."""
+    active = ntd_row > 0
+    packed, rank = rbucket.pack(ntd_row.astype(F32) * q, active,
+                                roll=tpu_roll)
+    c = prefix_sum_tiles(packed, roll=tpu_roll)
+    m = jnp.minimum(jnp.sum(active.astype(jnp.int32)), cap)
+    return c, m, active, rank
+
+
 def _sweep_tile(T: int, n_blk: int, r_cap: int, alpha: float, beta: float,
-                beta_bar: float, tok_doc, tok_wrd, tok_valid, tok_bound,
-                z_tile, u_tile, nt0, F0,
+                beta_bar: float, tok, z_out, nt_ref, tree: _Tree,
                 ntd_load, ntd_store, nwt_load, nwt_store,
                 rb_load=None, rb_store=None):
     """Exact Alg. 3 chain over one token tile.
 
-    Row access to the doc-topic / word-topic tables is abstracted behind
-    ``*_load(idx) -> (T,)`` / ``*_store(idx, row)`` so the single-block and
-    cell-batch kernels share the float-op order exactly.  The r-bucket
-    draw runs over the capacity-``r_cap`` compacted topic vector: with
-    ``rb_load``/``rb_store`` unset (dense mode) it is recomputed from the
-    decremented doc row per token; set, it is loaded from / stored to the
-    per-doc side table (``rb_load(d) -> (topics, counts)``,
-    ``rb_store(d, topics, counts)``) and maintained incrementally.
+    ``tok(k)`` reads token ``k``'s scalars ``(d, w, valid, boundary, u01,
+    t_old)`` and ``z_out(k, t)`` writes its new topic.  Row access to the
+    doc-topic / word-topic tables is abstracted behind ``*_load(idx) ->
+    (R, C)`` / ``*_store(idx, row)`` so every grid shares the float-op
+    order exactly.  Topic vectors are ``(R, C)`` tiles
+    (:func:`repro.core.prefix.topic_tile`).  With ``rb_load``/``rb_store``
+    unset (dense mode) the
+    r-vector is compacted from the decremented doc row per token; set, it
+    is loaded from / stored to the per-doc side table (``rb_load(d) ->
+    (topics, counts)``, ``rb_store(d, topics, counts)``) and maintained
+    incrementally.
     """
 
-    def q_of(nwt_row, nt):
-        return (nwt_row.astype(F32) + beta) / (nt.astype(F32) + beta_bar)
-
-    def body(k, carry):
-        z_tile, nt, F = carry
-        d, w = tok_doc[k], tok_wrd[k]
-        valid, boundary = tok_valid[k] != 0, tok_bound[k] != 0
-        u01 = u_tile[k]
-        t_old = z_tile[k]
+    def body(k, nt):
+        d, w, valid, boundary, u01, t_old = tok(k)
+        flat = topic_iota(topic_tile(T))
         one = valid.astype(jnp.int32)
 
-        ntd_row = ntd_load(d)                         # (T,) doc-topic row
-        nwt_row = nwt_load(w)                         # (T,) word-topic row
+        ntd_row = ntd_load(d)                         # doc-topic row tile
+        nwt_row = nwt_load(w)                         # word-topic row tile
 
         # Word boundary: rebuild the tree for the incoming word's q vector
-        # (cond, not where: the Θ(T) build must not run on interior tokens).
-        F = jax.lax.cond(boundary,
-                         lambda: ftree.build(q_of(nwt_row, nt)),
-                         lambda: F)
+        # (only boundary tokens pay the Θ(T) build).
+        @pl.when(boundary)
+        def _rebuild():
+            tree.build((nwt_row.astype(F32) + beta)
+                       / (nt.astype(F32) + beta_bar))
+
+        def leaf_value(nwt_row, nt, t):
+            return ((_pick(nwt_row, t).astype(F32) + beta)
+                    / (_pick(nt, t).astype(F32) + beta_bar))
 
         # --- decrement (Alg. 3 inner loop, masked) ------------------------
-        ntd_row = ntd_row.at[t_old].add(-one)
-        nwt_row = nwt_row.at[t_old].add(-one)
-        nt = nt.at[t_old].add(-one)
-        new_leaf = ((nwt_row[t_old].astype(F32) + beta)
-                    / (nt[t_old].astype(F32) + beta_bar))
-        F = ftree.set_leaf(F, t_old,
-                           jnp.where(valid, new_leaf, F[T + t_old]))
+        dec = jnp.where(flat == t_old, one, 0)
+        ntd_row, nwt_row, nt = ntd_row - dec, nwt_row - dec, nt - dec
+        tree.set_leaf(t_old, jnp.where(valid, leaf_value(nwt_row, nt, t_old),
+                                       tree.leaf(t_old)), flat)
 
         # --- two-level draw p = α·q + r (eq. (6), doc-sparse r-bucket) -----
-        q = ftree.leaves(F)
+        q = tree.mirror[...]
         if rb_load is None:
-            topics_d, counts_d = rbucket.compact_row(ntd_row, r_cap)
+            c, m, active, rank = _dense_r_draw(ntd_row, q, r_cap)
+            r_mass = _pick(c, r_cap - 1)
         else:
             topics_d, counts_d = rb_load(d)
             topics_d, counts_d = rbucket.decrement(topics_d, counts_d,
                                                    t_old, valid)
-        c = rbucket.r_cumsum(topics_d, counts_d, q)
-        r_mass = c[-1]
-        q_total = ftree.total(F)
+            c = rbucket.r_cumsum(topics_d, counts_d, q.reshape(T))
+            r_mass = c[-1]
+        q_total = tree.total()
         norm = alpha * q_total + r_mass
         u_val = u01 * norm
         in_r = u_val < r_mass
-        t_r = rbucket.pick(topics_d, counts_d, c, u_val)
-        t_q = ftree.sample(F, jnp.clip((u_val - r_mass)
-                                       / jnp.maximum(alpha * q_total, 1e-30),
-                                       0.0, 1.0 - 1e-7))
+        if rb_load is None:
+            below = jnp.sum(((c <= u_val) & (flat < r_cap))
+                            .astype(jnp.int32))
+            j_r = jnp.minimum(below, jnp.maximum(m - 1, 0))
+            t_r = jnp.sum(jnp.where(active & (rank == j_r), flat, 0))
+        else:
+            t_r = rbucket.pick(topics_d, counts_d, c, u_val)
+        t_q = tree.sample(jnp.clip((u_val - r_mass)
+                                   / jnp.maximum(alpha * q_total, 1e-30),
+                                   0.0, 1.0 - 1e-7))
         t_new = jnp.where(valid, jnp.where(in_r, t_r, t_q), t_old)
 
         # --- increment -----------------------------------------------------
-        ntd_row = ntd_row.at[t_new].add(one)
-        nwt_row = nwt_row.at[t_new].add(one)
-        nt = nt.at[t_new].add(one)
-        new_leaf2 = ((nwt_row[t_new].astype(F32) + beta)
-                     / (nt[t_new].astype(F32) + beta_bar))
-        F = ftree.set_leaf(F, t_new,
-                           jnp.where(valid, new_leaf2, F[T + t_new]))
+        inc = jnp.where(flat == t_new, one, 0)
+        ntd_row, nwt_row, nt = ntd_row + inc, nwt_row + inc, nt + inc
+        tree.set_leaf(t_new, jnp.where(valid, leaf_value(nwt_row, nt, t_new),
+                                       tree.leaf(t_new)), flat)
 
         if rb_store is not None:
             topics_d, counts_d = rbucket.increment(topics_d, counts_d,
@@ -197,14 +314,17 @@ def _sweep_tile(T: int, n_blk: int, r_cap: int, alpha: float, beta: float,
             rb_store(d, topics_d, counts_d)
         ntd_store(d, ntd_row)
         nwt_store(w, nwt_row)
-        z_tile = z_tile.at[k].set(t_new)
-        return z_tile, nt, F
+        z_out(k, t_new)
+        return nt
 
-    return jax.lax.fori_loop(0, n_blk, body, (z_tile, nt0, F0))
+    nt_ref[...] = jax.lax.fori_loop(0, n_blk, body, nt_ref[...])
 
 
-def _rb_accessors(tpc_ref, cnt_ref):
+def _rb_kw(sparse, tpc_ref, cnt_ref):
     """Row load/store on the whole-VMEM per-doc side tables (sparse mode)."""
+    if not sparse:
+        return {}
+
     def load(d):
         return (tpc_ref[pl.ds(d, 1), :][0], cnt_ref[pl.ds(d, 1), :][0])
 
@@ -212,58 +332,278 @@ def _rb_accessors(tpc_ref, cnt_ref):
         tpc_ref[pl.ds(d, 1), :] = topics[None]
         cnt_ref[pl.ds(d, 1), :] = counts[None]
 
+    return dict(rb_load=load, rb_store=store)
+
+
+def _row_access(ref, lead=()):
+    """(load, store) of one ``(R, C)`` row tile of a VMEM table ref
+    (``lead`` indexes a leading block axis)."""
+    load = lambda i: ref[lead + (i,)]
+    store = lambda i, row: ref.__setitem__(lead + (i,), row)
     return load, store
 
 
-def _rb_kw(sparse, tpc_ref, cnt_ref):
-    if not sparse:
-        return {}
-    rb_load, rb_store = _rb_accessors(tpc_ref, cnt_ref)
-    return dict(rb_load=rb_load, rb_store=rb_store)
+def _tok_access(tok_refs, z_ref):
+    """Scalar readers of the six SMEM token tiles + the ``z'`` writer."""
+    doc, wrd, valid, bound, z_in, u = tok_refs
+
+    def tok(k):
+        at = (0, 0, k)
+        return (doc[at], wrd[at], valid[at] != 0, bound[at] != 0, u[at],
+                z_in[at])
+
+    def z_out(k, t):
+        z_ref[0, 0, k] = t
+
+    return tok, z_out
 
 
-def _kernel(T: int, n_blk: int, r_cap: int, sparse: bool, alpha: float,
-            beta: float, beta_bar: float, *refs):
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[:9]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[9:11]
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref, tpc_ref, cnt_ref = refs[11:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref = refs[9:]
-    first = pl.program_id(0) == 0
+def _split_refs(refs, n_prefetch, sparse):
+    """Unpack a kernel's refs: scalar prefetch, the nine inputs, the
+    optional side tables, the outputs and the scratch buffers."""
+    pre, rest = refs[:n_prefetch], refs[n_prefetch:]
+    ins, rest = rest[:9], rest[9:]
+    rb_in, rest = (rest[:2], rest[2:]) if sparse else ((), rest)
+    outs, rest = rest[:6], rest[6:]
+    rb_out, rest = (rest[:2], rest[2:]) if sparse else ((None, None), rest)
+    scratch = rest
+    return pre, ins, rb_in, outs, rb_out, scratch
 
+
+def _make_kernel(T, n_blk, r_cap, sparse, alpha, beta, beta_bar, *,
+                 n_prefetch, grid_pos, nwt_lead, cell_start_of, doc_rows):
+    """Build one of the six kernel bodies.
+
+    ``grid_pos(pre)`` → (first, last, slab index g, previous g) in raster
+    order; ``cell_start_of(pre)`` → whether this step starts a new
+    word-topic block (``None``: a single whole block); ``nwt_lead`` the
+    leading block index of ``n_wt`` rows; ``doc_rows > 0`` pages
+    ``(doc_rows, T)`` slabs of an HBM ``n_td``.
+    """
+    docs = doc_rows > 0
+
+    def kernel(*refs):
+        pre, ins, rb_in, outs, rb_out, scratch = _split_refs(
+            refs, n_prefetch, sparse)
+        (tok_doc, tok_wrd, tok_valid, tok_bound, z_in, u,
+         ntd_in_ref, nwt_in_ref, nt_in_ref) = ins
+        z_ref, ntd_ref, nwt_ref, nt_ref, fint_out, fleaf_out = outs
+        tpc_ref, cnt_ref = rb_out
+        f_int, f_leaf, mirror, sem = scratch[:4]
+        tree = _Tree(T, f_int, f_leaf, mirror, sem)
+        first, last, g, g_prev = grid_pos(pre)
+
+        @pl.when(first)
+        def _init():
+            if not docs:
+                ntd_ref[...] = ntd_in_ref[...]
+            nt_ref[...] = nt_in_ref[...]
+            tree.zero()
+            if sparse:
+                tpc_ref[...] = rb_in[0][...]
+                cnt_ref[...] = rb_in[1][...]
+
+        start = cell_start_of(pre)
+        if start is None:
+            @pl.when(first)
+            def _load_block():
+                nwt_ref[...] = nwt_in_ref[...]
+        else:
+            @pl.when(start)
+            def _load_block():
+                nwt_ref[...] = nwt_in_ref[...]
+
+        if docs:
+            slab = scratch[4]
+            _doc_slab_page(doc_rows, g, g_prev, first, ntd_ref, slab, sem)
+            ntd_load, ntd_store = _slab_accessors(slab, g, doc_rows)
+        else:
+            ntd_load, ntd_store = _row_access(ntd_ref)
+        nwt_load, nwt_store = _row_access(nwt_ref, nwt_lead)
+        tok, z_out = _tok_access(
+            (tok_doc, tok_wrd, tok_valid, tok_bound, z_in, u), z_ref)
+
+        _sweep_tile(T, n_blk, r_cap, alpha, beta, beta_bar, tok, z_out,
+                    nt_ref, tree, ntd_load, ntd_store, nwt_load, nwt_store,
+                    **_rb_kw(sparse, tpc_ref, cnt_ref))
+
+        @pl.when(last)
+        def _flush():
+            if docs:
+                _slab_copy(slab, ntd_ref.at[pl.ds(g * doc_rows, doc_rows)],
+                           sem)
+            tree.flush(fint_out, fleaf_out)
+
+    return kernel
+
+
+# ---------------------------------------------------------------------------
+# Doc-tiled paging: n_td stays in ANY/HBM, one (doc_rows, T) slab is paged
+# through a VMEM scratch by explicit DMA (module docstring).
+# ---------------------------------------------------------------------------
+def _slab_copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _doc_slab_page(doc_rows, g, g_prev, first, ntd_ref, slab, sem):
+    """Slab prologue of one grid step: at the first step pull the first
+    slab; at a slab switch, write the previous slab back and pull the
+    new one.  The table's input and output are one aliased HBM buffer,
+    so every page-in reads the counts as of the last write-back."""
     @pl.when(first)
     def _init():
-        ntd_ref[...] = ntd_in_ref[...]
-        nwt_ref[...] = nwt_in_ref[...]
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
+        _slab_copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
 
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[...], tok_wrd_ref[...], tok_valid_ref[...],
-        tok_bound_ref[...], z_in_ref[...], u_ref[...],
-        nt_ref[...], f_ref[...],
-        ntd_load=lambda d: ntd_ref[pl.ds(d, 1), :][0],
-        ntd_store=lambda d, row: ntd_ref.__setitem__(
-            (pl.ds(d, 1), slice(None)), row[None]),
-        nwt_load=lambda w: nwt_ref[pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile
-    nt_ref[...] = nt
-    f_ref[...] = F
+    @pl.when(jnp.logical_not(first) & (g != g_prev))
+    def _switch():
+        _slab_copy(slab, ntd_ref.at[pl.ds(g_prev * doc_rows, doc_rows)],
+                   sem)
+        _slab_copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
 
 
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "n_blk", "r_cap", "interpret"))
+def _slab_accessors(slab, g, doc_rows):
+    """Row load/store on the resident slab; ``tok_doc`` carries worker-local
+    doc indices, the slab holds rows [g·doc_rows, (g+1)·doc_rows).  A
+    valid token's row is always in the slab; a padding token's (doc 0)
+    may not be, so the index is clamped into the slab — its masked update
+    rewrites the row it read, which changes nothing — instead of
+    addressing VMEM outside the buffer."""
+    row = lambda d: jnp.clip(d - g * doc_rows, 0, doc_rows - 1)
+    load = lambda d: slab[row(d)]
+    store = lambda d, r: slab.__setitem__(row(d), r)
+    return load, store
+
+
+# ---------------------------------------------------------------------------
+# pallas_call assembly shared by the six entry points.
+# ---------------------------------------------------------------------------
+def _whole(*shape):
+    """A block that is the whole array, at every grid step."""
+    return pl.BlockSpec(shape, lambda *g: (0,) * len(shape))
+
+
+def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
+                grid, tok_index, nwt_index, grid_pos, cell_start_of,
+                doc_rows, alpha, beta, beta_bar, r_cap, n_blk, vmem_limit,
+                interpret):
+    """One fused-sweep ``pallas_call``.
+
+    The six token streams (any shape, ``n_blk`` tokens per tile) ride as
+    ``(n_tiles, 1, n_blk)`` SMEM blocks — last two dims whole, so any
+    ``n_blk`` meets the TPU block-shape rule and XLA's HBM layout — with
+    ``tok_index(*grid_ids) -> tile``.  Every topic axis is laid out as
+    ``(R, C)`` row tiles (:func:`repro.core.prefix.topic_tile`), so a
+    table row is whole vregs behind one dynamic leading index.
+    ``nwt_index`` pages one block of a ``(k, J, T)`` word-topic queue per
+    step (``None``: one whole ``(J, T)`` block); ``doc_rows > 0`` leaves
+    ``n_td`` in HBM, aliased input to output, paged by slab.  The tree
+    sits in SMEM scratch, flushed to two HBM outputs.  Returns (z',
+    n_td', n_wt', n_t', F (2T,)) in the callers' shapes, plus the side
+    tables in sparse mode.
+    """
+    T = n_t.shape[-1]
+    tile = topic_tile(T)
+    rows = lambda a: a.reshape(a.shape[:-1] + tile)
+    n_td, n_wt, n_t = rows(n_td), rows(n_wt), rows(n_t)
+    toks = [a.reshape(-1, 1, n_blk) for a in tokens]
+    cap = int(r_cap) if r_cap else T
+    sparse = topics is not None
+    docs = doc_rows > 0
+    kernel = _make_kernel(
+        T, n_blk, cap, sparse, float(alpha), float(beta), float(beta_bar),
+        n_prefetch=len(prefetch), grid_pos=grid_pos,
+        nwt_lead=() if nwt_index is None else (0,),
+        cell_start_of=cell_start_of, doc_rows=doc_rows)
+
+    smem_tile = pl.BlockSpec((1, 1, n_blk),
+                             lambda *g: tok_index(*g) + (0, 0),
+                             memory_space=pltpu.SMEM)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    ntd_spec = any_spec if docs else _whole(*n_td.shape)
+    nwt_spec = (_whole(*n_wt.shape) if nwt_index is None else
+                pl.BlockSpec((1,) + n_wt.shape[1:],
+                             lambda *g: (nwt_index(*g), 0, 0, 0)))
+    rb_specs = [_whole(*topics.shape)] * 2 if sparse else []
+    scratch = [pltpu.SMEM((T,), F32), pltpu.SMEM(tile, F32),
+               pltpu.VMEM(tile, F32), pltpu.SemaphoreType.DMA]
+    if docs:
+        scratch.append(pltpu.VMEM((doc_rows,) + tile, jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=grid,
+        in_specs=[*([smem_tile] * 6),                    # token stream
+                  ntd_spec, nwt_spec, _whole(*tile),     # count tables
+                  *rb_specs],                            # side tables
+        out_specs=[smem_tile,                            # z'
+                   ntd_spec, nwt_spec, _whole(*tile),    # count tables
+                   any_spec, any_spec,                   # F+tree (HBM)
+                   *rb_specs],                           # side tables
+        scratch_shapes=scratch,
+    )
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[shape(toks[4]), shape(n_td), shape(n_wt), shape(n_t),
+                   jax.ShapeDtypeStruct((T,), F32),
+                   jax.ShapeDtypeStruct(tile, F32),
+                   *([shape(topics)] * 2 if sparse else [])],
+        compiler_params=(pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+                         if vmem_limit else None),
+        # doc-tiled: n_td's HBM input is its output, paged in place
+        input_output_aliases={len(prefetch) + 6: 1} if docs else {},
+        interpret=interpret,
+    )(*prefetch, *toks, n_td, n_wt, n_t,
+      *((topics, counts) if sparse else ()))
+    z, n_td, n_wt, n_t, f_int, f_leaf = out[:6]
+    flat = lambda a: a.reshape(a.shape[:-2] + (T,))
+    F = jnp.concatenate([f_int, flat(f_leaf)])
+    return (z.reshape(tokens[4].shape), flat(n_td), flat(n_wt), flat(n_t),
+            F) + tuple(out[6:])
+
+
+_STATIC = ("alpha", "beta", "beta_bar", "n_blk", "r_cap", "interpret",
+           "vmem_limit")
+
+
+def _first_last_1d(pre):
+    t = pl.program_id(0)
+    return t == 0, t == pl.num_programs(0) - 1
+
+
+def _ragged_cell_start(cot):
+    t = pl.program_id(0)
+    return (t == 0) | (cot[t] != cot[jnp.maximum(t - 1, 0)])
+
+
+def _slab_pos_1d(dto):
+    """(first, last, slab, previous slab) on a flat tile grid."""
+    t = pl.program_id(0)
+    return (t == 0, t == pl.num_programs(0) - 1, dto[t],
+            dto[jnp.maximum(t - 1, 0)])
+
+
+def _slab_pos_cells(dto):
+    """(first, last, slab, previous slab) on a ``(k, tiles)`` grid; the
+    previous step in raster order is the last tile of the previous cell
+    when ``t == 0`` (unused garbage at the very first step)."""
+    c, t = pl.program_id(0), pl.program_id(1)
+    n_c, n_t_g = pl.num_programs(0), pl.num_programs(1)
+    pc = jnp.where(t == 0, jnp.maximum(c - 1, 0), c)
+    pt = jnp.where(t == 0, n_t_g - 1, t - 1)
+    return ((c == 0) & (t == 0), (c == n_c - 1) & (t == n_t_g - 1),
+            dto[c, t], dto[pc, pt])
+
+
+def _first_last_cells(pre):
+    c, t = pl.program_id(0), pl.program_id(1)
+    return ((c == 0) & (t == 0),
+            (c == pl.num_programs(0) - 1) & (t == pl.num_programs(1) - 1))
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_sweep_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                        tok_valid: jax.Array, tok_bound: jax.Array,
                        z: jax.Array, u: jax.Array,
@@ -271,8 +611,8 @@ def fused_sweep_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                        topics: jax.Array | None = None,
                        counts: jax.Array | None = None, *,
                        alpha: float, beta: float, beta_bar: float,
-                       r_cap: int = 0,
-                       n_blk: int = N_BLK, interpret: bool = True):
+                       r_cap: int = 0, n_blk: int = N_BLK,
+                       vmem_limit: int = 0, interpret: bool):
     """One fused F+LDA sweep over a padded token stream.
 
     Shapes: tok_* / z / u are (N,) with N % n_blk == 0; n_td (I, T) i32;
@@ -282,97 +622,20 @@ def fused_sweep_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
     ``r_cap`` (static; 0 → T) is the compacted r-vector capacity.  Passing
     ``topics``/``counts`` side tables ((I, r_cap) i32 each) selects sparse
     r-mode: they are maintained in VMEM and returned appended — a 7-tuple.
+    ``vmem_limit`` (bytes, 0 = the compiler's default scoped limit) is
+    passed to Mosaic.
     """
-    n = tok_doc.shape[0]
-    I, T = n_td.shape
-    J = n_wt.shape[0]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    grid = (n // n_blk,)
-
-    tile = lambda: pl.BlockSpec((n_blk,), lambda b: (b,))
-    whole = lambda *shape: pl.BlockSpec(shape, lambda b: (0,) * len(shape))
-
-    rb_specs = [whole(I, cap), whole(I, cap)] if sparse else []
-    rb_shape = ([jax.ShapeDtypeStruct((I, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    return pl.pallas_call(
-        functools.partial(_kernel, T, n_blk, cap, sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid=grid,
-        in_specs=[
-            tile(), tile(), tile(), tile(), tile(), tile(),   # token stream
-            whole(I, T), whole(J, T), whole(T),               # count tables
-            *rb_specs,                                        # side tables
-        ],
-        out_specs=[
-            tile(),                                           # z'
-            whole(I, T), whole(J, T), whole(T),               # tables
-            whole(2 * T),                                     # final F+tree
-            *rb_specs,                                        # side tables
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(tok_doc, tok_wrd, tok_valid, tok_bound, z, u, n_td, n_wt, n_t,
-      *rb_args)
+    return _fused_call(
+        (), (tok_doc, tok_wrd, tok_valid, tok_bound, z, u), n_td, n_wt,
+        n_t, topics, counts, grid=(tok_doc.shape[0] // n_blk,),
+        tok_index=lambda b: (b,), nwt_index=None,
+        grid_pos=lambda pre: _first_last_1d(pre) + (None, None),
+        cell_start_of=lambda pre: None, doc_rows=0, alpha=alpha, beta=beta,
+        beta_bar=beta_bar, r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
+        interpret=interpret)
 
 
-def _cells_kernel(T: int, n_blk: int, r_cap: int, sparse: bool,
-                  alpha: float, beta: float, beta_bar: float, *refs):
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[:9]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[9:11]
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref, tpc_ref, cnt_ref = refs[11:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref = refs[9:]
-    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
-    cell_start = pl.program_id(1) == 0
-
-    @pl.when(first)
-    def _init():
-        ntd_ref[...] = ntd_in_ref[...]
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
-
-    # New cell ⇒ new word-topic block paged into the output accumulator.
-    @pl.when(cell_start)
-    def _load_block():
-        nwt_ref[...] = nwt_in_ref[...]
-
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[0], tok_wrd_ref[0], tok_valid_ref[0],
-        tok_bound_ref[0], z_in_ref[0], u_ref[0],
-        nt_ref[...], f_ref[...],
-        ntd_load=lambda d: ntd_ref[pl.ds(d, 1), :][0],
-        ntd_store=lambda d, row: ntd_ref.__setitem__(
-            (pl.ds(d, 1), slice(None)), row[None]),
-        nwt_load=lambda w: nwt_ref[0, pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (0, pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile[None]
-    nt_ref[...] = nt
-    f_ref[...] = F
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "n_blk", "r_cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_sweep_cells_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                              tok_valid: jax.Array, tok_bound: jax.Array,
                              z: jax.Array, u: jax.Array,
@@ -381,113 +644,31 @@ def fused_sweep_cells_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                              topics: jax.Array | None = None,
                              counts: jax.Array | None = None, *,
                              alpha: float, beta: float, beta_bar: float,
-                             r_cap: int = 0,
-                             n_blk: int = N_BLK, interpret: bool = True):
+                             r_cap: int = 0, n_blk: int = N_BLK,
+                             vmem_limit: int = 0, interpret: bool):
     """One fused F+LDA sweep over a batch of k cells (a nomad block queue).
 
     Shapes: tok_* / z / u are (k, L) with L % n_blk == 0; n_td (I, T) i32
     shared across cells; n_wt (k, J, T) i32, one word-topic block per cell
     (``tok_wrd`` is block-local); n_t (T,) i32.  Cells are swept in order
-    c = 0..k-1 with the exact chain carried through ``n_td``/``n_t``/``F``;
+    c = 0..k-1 with the exact chain carried through ``n_td``/``n_t``/F;
     returns (z', n_td', n_wt', n_t', F), plus the ``(topics, counts)``
     side tables appended when they are passed (sparse r-mode).
     """
     k, L = tok_doc.shape
-    I, T = n_td.shape
-    J = n_wt.shape[1]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    grid = (k, L // n_blk)
-
-    tile = lambda: pl.BlockSpec((1, n_blk), lambda c, t: (c, t))
-    blk = lambda: pl.BlockSpec((1, J, T), lambda c, t: (c, 0, 0))
-    whole = lambda *shape: pl.BlockSpec(shape,
-                                        lambda c, t: (0,) * len(shape))
-
-    rb_specs = [whole(I, cap), whole(I, cap)] if sparse else []
-    rb_shape = ([jax.ShapeDtypeStruct((I, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    return pl.pallas_call(
-        functools.partial(_cells_kernel, T, n_blk, cap, sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid=grid,
-        in_specs=[
-            tile(), tile(), tile(), tile(), tile(), tile(),   # token stream
-            whole(I, T), blk(), whole(T),                     # count tables
-            *rb_specs,                                        # side tables
-        ],
-        out_specs=[
-            tile(),                                           # z'
-            whole(I, T), blk(), whole(T),                     # tables
-            whole(2 * T),                                     # final F+tree
-            *rb_specs,                                        # side tables
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, L), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((k, J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(tok_doc, tok_wrd, tok_valid, tok_bound, z, u, n_td, n_wt, n_t,
-      *rb_args)
+    tiles = L // n_blk
+    return _fused_call(
+        (), (tok_doc, tok_wrd, tok_valid, tok_bound, z, u), n_td, n_wt,
+        n_t, topics, counts, grid=(k, tiles),
+        tok_index=lambda c, t: (c * tiles + t,),
+        nwt_index=lambda c, t: c,
+        grid_pos=lambda pre: _first_last_cells(pre) + (None, None),
+        cell_start_of=lambda pre: pl.program_id(1) == 0, doc_rows=0,
+        alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
 
 
-def _ragged_kernel(T: int, n_blk: int, r_cap: int, sparse: bool,
-                   alpha: float, beta: float, beta_bar: float, *refs):
-    cot_ref = refs[0]                                  # scalar prefetch
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[1:10]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[10:12]
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref, tpc_ref, cnt_ref = refs[12:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_ref, nwt_ref, nt_ref, f_ref = refs[10:]
-    t = pl.program_id(0)
-    first = t == 0
-    # Cell start: the tile→cell map steps (it is non-decreasing, one
-    # contiguous tile run per cell) — page the cell's block into the
-    # output accumulator, exactly like the cell-batch grid's first tile.
-    cell_start = first | (cot_ref[t] != cot_ref[jnp.maximum(t - 1, 0)])
-
-    @pl.when(first)
-    def _init():
-        ntd_ref[...] = ntd_in_ref[...]
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
-
-    @pl.when(cell_start)
-    def _load_block():
-        nwt_ref[...] = nwt_in_ref[...]
-
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[...], tok_wrd_ref[...], tok_valid_ref[...],
-        tok_bound_ref[...], z_in_ref[...], u_ref[...],
-        nt_ref[...], f_ref[...],
-        ntd_load=lambda d: ntd_ref[pl.ds(d, 1), :][0],
-        ntd_store=lambda d, row: ntd_ref.__setitem__(
-            (pl.ds(d, 1), slice(None)), row[None]),
-        nwt_load=lambda w: nwt_ref[0, pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (0, pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile
-    nt_ref[...] = nt
-    f_ref[...] = F
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "n_blk", "r_cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_sweep_ragged_pallas(cell_of_tile: jax.Array,
                               tok_doc: jax.Array, tok_wrd: jax.Array,
                               tok_valid: jax.Array, tok_bound: jax.Array,
@@ -497,166 +678,31 @@ def fused_sweep_ragged_pallas(cell_of_tile: jax.Array,
                               topics: jax.Array | None = None,
                               counts: jax.Array | None = None, *,
                               alpha: float, beta: float, beta_bar: float,
-                              r_cap: int = 0,
-                              n_blk: int, interpret: bool = True):
+                              r_cap: int = 0, n_blk: int,
+                              vmem_limit: int = 0, interpret: bool):
     """One fused F+LDA sweep over a ragged cell stream (a nomad queue).
 
     Shapes: tok_* / z / u are (S,) with ``S = n_tiles·n_blk``;
     cell_of_tile (n_tiles,) i32, non-decreasing, values in [0, k);
     n_td (I, T) i32; n_wt (k, J, T) i32, one word-topic block per cell
     (``tok_wrd`` is block-local); n_t (T,) i32.  Tiles run in sequence
-    with ``n_td``/``n_t``/``F`` carried; tile ``t`` addresses word-topic
+    with ``n_td``/``n_t``/F carried; tile ``t`` addresses word-topic
     block ``cell_of_tile[t]``, paged by scalar-prefetched index map.
     Returns (z', n_td', n_wt', n_t', F), plus the ``(topics, counts)``
     side tables appended when they are passed (sparse r-mode).
     """
-    n = tok_doc.shape[0]
-    I, T = n_td.shape
-    k, J = n_wt.shape[0], n_wt.shape[1]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    n_tiles = n // n_blk
-
-    rb_in = ([pl.BlockSpec((I, cap), lambda t, cot: (0, 0))] * 2
-             if sparse else [])
-    rb_out = ([pl.BlockSpec((I, cap), lambda t, cot: (0, 0))] * 2
-              if sparse else [])
-    rb_shape = ([jax.ShapeDtypeStruct((I, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[
-            *(pl.BlockSpec((n_blk,), lambda t, cot: (t,))
-              for _ in range(6)),                          # token stream
-            pl.BlockSpec((I, T), lambda t, cot: (0, 0)),
-            pl.BlockSpec((1, J, T), lambda t, cot: (cot[t], 0, 0)),
-            pl.BlockSpec((T,), lambda t, cot: (0,)),
-            *rb_in,                                        # side tables
-        ],
-        out_specs=[
-            pl.BlockSpec((n_blk,), lambda t, cot: (t,)),   # z'
-            pl.BlockSpec((I, T), lambda t, cot: (0, 0)),
-            pl.BlockSpec((1, J, T), lambda t, cot: (cot[t], 0, 0)),
-            pl.BlockSpec((T,), lambda t, cot: (0,)),
-            pl.BlockSpec((2 * T,), lambda t, cot: (0,)),   # final F+tree
-            *rb_out,                                       # side tables
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, T, n_blk, cap, sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((k, J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(cell_of_tile, tok_doc, tok_wrd, tok_valid, tok_bound, z, u,
-      n_td, n_wt, n_t, *rb_args)
+    return _fused_call(
+        (cell_of_tile,), (tok_doc, tok_wrd, tok_valid, tok_bound, z, u),
+        n_td, n_wt, n_t, topics, counts,
+        grid=(tok_doc.shape[0] // n_blk,), tok_index=lambda t, cot: (t,),
+        nwt_index=lambda t, cot: cot[t],
+        grid_pos=lambda pre: _first_last_1d(pre) + (None, None),
+        cell_start_of=lambda pre: _ragged_cell_start(pre[0]), doc_rows=0,
+        alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
 
 
-# ---------------------------------------------------------------------------
-# Doc-tiled variants: n_td stays in ANY/HBM, one (doc_rows, T) slab is
-# paged through a VMEM scratch by explicit DMA (module docstring).
-# ---------------------------------------------------------------------------
-def _slab_copy(src, dst, sem):
-    cp = pltpu.make_async_copy(src, dst, sem)
-    cp.start()
-    cp.wait()
-
-
-def _doc_slab_page(doc_rows, g, g_prev, first,
-                   ntd_in_ref, ntd_out_ref, slab, sem):
-    """Slab prologue of one grid step: at the first step, bulk-copy the
-    whole table input→output and pull the first slab; at a slab switch,
-    write the previous slab back and pull the new one.  All reads go
-    through the output buffer, so recurring slabs see every prior
-    write-back."""
-    @pl.when(first)
-    def _init():
-        _slab_copy(ntd_in_ref, ntd_out_ref, sem)
-        _slab_copy(ntd_out_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
-
-    @pl.when(jnp.logical_not(first) & (g != g_prev))
-    def _switch():
-        _slab_copy(slab, ntd_out_ref.at[pl.ds(g_prev * doc_rows, doc_rows)],
-                   sem)
-        _slab_copy(ntd_out_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
-
-
-def _slab_accessors(slab, g, doc_rows):
-    """Row load/store on the resident slab; ``tok_doc`` carries worker-local
-    doc indices, the slab holds rows [g·doc_rows, (g+1)·doc_rows)."""
-    row0 = g * doc_rows
-    load = lambda d: slab[pl.ds(d - row0, 1), :][0]
-    store = lambda d, row: slab.__setitem__(
-        (pl.ds(d - row0, 1), slice(None)), row[None])
-    return load, store
-
-
-def _docs_kernel(T: int, n_blk: int, doc_rows: int, r_cap: int,
-                 sparse: bool, alpha: float, beta: float, beta_bar: float,
-                 *refs):
-    dto_ref = refs[0]                                  # scalar prefetch
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[1:10]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[10:12]
-        (z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref,
-         tpc_ref, cnt_ref) = refs[12:19]
-        slab, sem = refs[19:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref = refs[10:15]
-        slab, sem = refs[15:]
-    t = pl.program_id(0)
-    first = t == 0
-    g = dto_ref[t]
-    g_prev = dto_ref[jnp.maximum(t - 1, 0)]
-
-    @pl.when(first)
-    def _init():
-        nwt_ref[...] = nwt_in_ref[...]
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
-
-    _doc_slab_page(doc_rows, g, g_prev, first, ntd_in_ref, ntd_out_ref,
-                   slab, sem)
-    ntd_load, ntd_store = _slab_accessors(slab, g, doc_rows)
-
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[...], tok_wrd_ref[...], tok_valid_ref[...],
-        tok_bound_ref[...], z_in_ref[...], u_ref[...],
-        nt_ref[...], f_ref[...],
-        ntd_load=ntd_load, ntd_store=ntd_store,
-        nwt_load=lambda w: nwt_ref[pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile
-    nt_ref[...] = nt
-    f_ref[...] = F
-
-    @pl.when(t == pl.num_programs(0) - 1)
-    def _flush():
-        _slab_copy(slab, ntd_out_ref.at[pl.ds(g * doc_rows, doc_rows)], sem)
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "doc_rows", "n_blk", "r_cap",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
 def fused_sweep_docs_pallas(doc_tile_of: jax.Array,
                             tok_doc: jax.Array, tok_wrd: jax.Array,
                             tok_valid: jax.Array, tok_bound: jax.Array,
@@ -667,8 +713,8 @@ def fused_sweep_docs_pallas(doc_tile_of: jax.Array,
                             counts: jax.Array | None = None, *,
                             alpha: float, beta: float, beta_bar: float,
                             doc_rows: int, r_cap: int = 0,
-                            n_blk: int = N_BLK,
-                            interpret: bool = True):
+                            n_blk: int = N_BLK, vmem_limit: int = 0,
+                            interpret: bool):
     """Doc-tiled twin of :func:`fused_sweep_pallas`.
 
     ``doc_tile_of`` is the (n // n_blk,) per-tile slab map; ``n_td`` rows
@@ -678,125 +724,17 @@ def fused_sweep_docs_pallas(doc_tile_of: jax.Array,
     tables stay whole-VMEM (they are a factor T/r_cap smaller than the
     table the slab paging evicts) and are not padded to slab multiples.
     """
-    n = tok_doc.shape[0]
-    I, T = n_td.shape
-    J = n_wt.shape[0]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    I_tab = topics.shape[0] if sparse else 0
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-
-    rb_specs = ([pl.BlockSpec((I_tab, cap), lambda t, dto: (0, 0))] * 2
-                if sparse else [])
-    rb_shape = ([jax.ShapeDtypeStruct((I_tab, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n // n_blk,),
-        in_specs=[
-            *(pl.BlockSpec((n_blk,), lambda t, dto: (t,))
-              for _ in range(6)),                          # token stream
-            any_spec,                                      # n_td (HBM)
-            pl.BlockSpec((J, T), lambda t, dto: (0, 0)),
-            pl.BlockSpec((T,), lambda t, dto: (0,)),
-            *rb_specs,                                     # side tables
-        ],
-        out_specs=[
-            pl.BlockSpec((n_blk,), lambda t, dto: (t,)),   # z'
-            any_spec,                                      # n_td' (HBM)
-            pl.BlockSpec((J, T), lambda t, dto: (0, 0)),
-            pl.BlockSpec((T,), lambda t, dto: (0,)),
-            pl.BlockSpec((2 * T,), lambda t, dto: (0,)),   # final F+tree
-            *rb_specs,                                     # side tables
-        ],
-        scratch_shapes=[pltpu.VMEM((doc_rows, T), jnp.int32),
-                        pltpu.SemaphoreType.DMA],
-    )
-    return pl.pallas_call(
-        functools.partial(_docs_kernel, T, n_blk, int(doc_rows), cap,
-                          sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(doc_tile_of, tok_doc, tok_wrd, tok_valid, tok_bound, z, u,
-      n_td, n_wt, n_t, *rb_args)
+    return _fused_call(
+        (doc_tile_of,), (tok_doc, tok_wrd, tok_valid, tok_bound, z, u),
+        n_td, n_wt, n_t, topics, counts,
+        grid=(tok_doc.shape[0] // n_blk,), tok_index=lambda t, dto: (t,),
+        nwt_index=None, grid_pos=lambda pre: _slab_pos_1d(pre[0]),
+        cell_start_of=lambda pre: None, doc_rows=int(doc_rows),
+        alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
 
 
-def _cells_docs_kernel(T: int, n_blk: int, doc_rows: int, r_cap: int,
-                       sparse: bool, alpha: float, beta: float,
-                       beta_bar: float, *refs):
-    dto_ref = refs[0]                                  # scalar prefetch
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[1:10]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[10:12]
-        (z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref,
-         tpc_ref, cnt_ref) = refs[12:19]
-        slab, sem = refs[19:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref = refs[10:15]
-        slab, sem = refs[15:]
-    c, t = pl.program_id(0), pl.program_id(1)
-    n_c, n_t_g = pl.num_programs(0), pl.num_programs(1)
-    first = (c == 0) & (t == 0)
-    cell_start = t == 0
-    g = dto_ref[c, t]
-    # previous grid step in raster order (the last tile of the previous
-    # cell when t == 0); unused garbage at the very first step
-    pc = jnp.where(t == 0, jnp.maximum(c - 1, 0), c)
-    pt = jnp.where(t == 0, n_t_g - 1, t - 1)
-    g_prev = dto_ref[pc, pt]
-
-    @pl.when(first)
-    def _init():
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
-
-    @pl.when(cell_start)
-    def _load_block():
-        nwt_ref[...] = nwt_in_ref[...]
-
-    _doc_slab_page(doc_rows, g, g_prev, first, ntd_in_ref, ntd_out_ref,
-                   slab, sem)
-    ntd_load, ntd_store = _slab_accessors(slab, g, doc_rows)
-
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[0], tok_wrd_ref[0], tok_valid_ref[0],
-        tok_bound_ref[0], z_in_ref[0], u_ref[0],
-        nt_ref[...], f_ref[...],
-        ntd_load=ntd_load, ntd_store=ntd_store,
-        nwt_load=lambda w: nwt_ref[0, pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (0, pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile[None]
-    nt_ref[...] = nt
-    f_ref[...] = F
-
-    @pl.when((c == n_c - 1) & (t == n_t_g - 1))
-    def _flush():
-        _slab_copy(slab, ntd_out_ref.at[pl.ds(g * doc_rows, doc_rows)], sem)
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "doc_rows", "n_blk", "r_cap",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
 def fused_sweep_cells_docs_pallas(doc_tile_of: jax.Array,
                                   tok_doc: jax.Array, tok_wrd: jax.Array,
                                   tok_valid: jax.Array, tok_bound: jax.Array,
@@ -807,124 +745,25 @@ def fused_sweep_cells_docs_pallas(doc_tile_of: jax.Array,
                                   counts: jax.Array | None = None, *,
                                   alpha: float, beta: float, beta_bar: float,
                                   doc_rows: int, r_cap: int = 0,
-                                  n_blk: int = N_BLK,
-                                  interpret: bool = True):
+                                  n_blk: int = N_BLK, vmem_limit: int = 0,
+                                  interpret: bool):
     """Doc-tiled twin of :func:`fused_sweep_cells_pallas`; ``doc_tile_of``
     is the (k, L // n_blk) per-(cell, tile) slab map."""
     k, L = tok_doc.shape
-    I, T = n_td.shape
-    J = n_wt.shape[1]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    I_tab = topics.shape[0] if sparse else 0
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-
-    rb_specs = ([pl.BlockSpec((I_tab, cap), lambda c, t, dto: (0, 0))] * 2
-                if sparse else [])
-    rb_shape = ([jax.ShapeDtypeStruct((I_tab, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k, L // n_blk),
-        in_specs=[
-            *(pl.BlockSpec((1, n_blk), lambda c, t, dto: (c, t))
-              for _ in range(6)),                          # token stream
-            any_spec,                                      # n_td (HBM)
-            pl.BlockSpec((1, J, T), lambda c, t, dto: (c, 0, 0)),
-            pl.BlockSpec((T,), lambda c, t, dto: (0,)),
-            *rb_specs,                                     # side tables
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_blk), lambda c, t, dto: (c, t)),
-            any_spec,                                      # n_td' (HBM)
-            pl.BlockSpec((1, J, T), lambda c, t, dto: (c, 0, 0)),
-            pl.BlockSpec((T,), lambda c, t, dto: (0,)),
-            pl.BlockSpec((2 * T,), lambda c, t, dto: (0,)),
-            *rb_specs,                                     # side tables
-        ],
-        scratch_shapes=[pltpu.VMEM((doc_rows, T), jnp.int32),
-                        pltpu.SemaphoreType.DMA],
-    )
-    return pl.pallas_call(
-        functools.partial(_cells_docs_kernel, T, n_blk, int(doc_rows),
-                          cap, sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((k, L), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((k, J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(doc_tile_of, tok_doc, tok_wrd, tok_valid, tok_bound, z, u,
-      n_td, n_wt, n_t, *rb_args)
+    tiles = L // n_blk
+    return _fused_call(
+        (doc_tile_of,), (tok_doc, tok_wrd, tok_valid, tok_bound, z, u),
+        n_td, n_wt, n_t, topics, counts, grid=(k, tiles),
+        tok_index=lambda c, t, dto: (c * tiles + t,),
+        nwt_index=lambda c, t, dto: c,
+        grid_pos=lambda pre: _slab_pos_cells(pre[0]),
+        cell_start_of=lambda pre: pl.program_id(1) == 0,
+        doc_rows=int(doc_rows), alpha=alpha, beta=beta, beta_bar=beta_bar,
+        r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
+        interpret=interpret)
 
 
-def _ragged_docs_kernel(T: int, n_blk: int, doc_rows: int, r_cap: int,
-                        sparse: bool, alpha: float, beta: float,
-                        beta_bar: float, *refs):
-    cot_ref, dto_ref = refs[:2]                        # scalar prefetch
-    (tok_doc_ref, tok_wrd_ref, tok_valid_ref, tok_bound_ref,
-     z_in_ref, u_ref, ntd_in_ref, nwt_in_ref, nt_in_ref) = refs[2:11]
-    if sparse:
-        tpc_in_ref, cnt_in_ref = refs[11:13]
-        (z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref,
-         tpc_ref, cnt_ref) = refs[13:20]
-        slab, sem = refs[20:]
-    else:
-        tpc_ref = cnt_ref = None
-        z_ref, ntd_out_ref, nwt_ref, nt_ref, f_ref = refs[11:16]
-        slab, sem = refs[16:]
-    t = pl.program_id(0)
-    first = t == 0
-    cell_start = first | (cot_ref[t] != cot_ref[jnp.maximum(t - 1, 0)])
-    g = dto_ref[t]
-    g_prev = dto_ref[jnp.maximum(t - 1, 0)]
-
-    @pl.when(first)
-    def _init():
-        nt_ref[...] = nt_in_ref[...]
-        f_ref[...] = jnp.zeros((2 * T,), F32)
-        if sparse:
-            tpc_ref[...] = tpc_in_ref[...]
-            cnt_ref[...] = cnt_in_ref[...]
-
-    @pl.when(cell_start)
-    def _load_block():
-        nwt_ref[...] = nwt_in_ref[...]
-
-    _doc_slab_page(doc_rows, g, g_prev, first, ntd_in_ref, ntd_out_ref,
-                   slab, sem)
-    ntd_load, ntd_store = _slab_accessors(slab, g, doc_rows)
-
-    z_tile, nt, F = _sweep_tile(
-        T, n_blk, r_cap, alpha, beta, beta_bar,
-        tok_doc_ref[...], tok_wrd_ref[...], tok_valid_ref[...],
-        tok_bound_ref[...], z_in_ref[...], u_ref[...],
-        nt_ref[...], f_ref[...],
-        ntd_load=ntd_load, ntd_store=ntd_store,
-        nwt_load=lambda w: nwt_ref[0, pl.ds(w, 1), :][0],
-        nwt_store=lambda w, row: nwt_ref.__setitem__(
-            (0, pl.ds(w, 1), slice(None)), row[None]),
-        **_rb_kw(sparse, tpc_ref, cnt_ref))
-
-    z_ref[...] = z_tile
-    nt_ref[...] = nt
-    f_ref[...] = F
-
-    @pl.when(t == pl.num_programs(0) - 1)
-    def _flush():
-        _slab_copy(slab, ntd_out_ref.at[pl.ds(g * doc_rows, doc_rows)], sem)
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "beta_bar",
-                                             "doc_rows", "n_blk", "r_cap",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
 def fused_sweep_ragged_docs_pallas(cell_of_tile: jax.Array,
                                    doc_tile_of: jax.Array,
                                    tok_doc: jax.Array, tok_wrd: jax.Array,
@@ -937,63 +776,22 @@ def fused_sweep_ragged_docs_pallas(cell_of_tile: jax.Array,
                                    counts: jax.Array | None = None, *,
                                    alpha: float, beta: float,
                                    beta_bar: float, doc_rows: int,
-                                   r_cap: int = 0,
-                                   n_blk: int, interpret: bool = True):
+                                   r_cap: int = 0, n_blk: int,
+                                   vmem_limit: int = 0, interpret: bool):
     """Doc-tiled twin of :func:`fused_sweep_ragged_pallas`: two
     scalar-prefetch maps drive the paging — ``cell_of_tile`` pages the
     word-topic block (BlockSpec window, visited once per cell) and
     ``doc_tile_of`` pages the doc-topic slab (explicit DMA, slabs
     recur)."""
-    n = tok_doc.shape[0]
-    I, T = n_td.shape
-    k, J = n_wt.shape[0], n_wt.shape[1]
-    cap = int(r_cap) if r_cap else T
-    sparse = topics is not None
-    I_tab = topics.shape[0] if sparse else 0
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-
-    rb_specs = ([pl.BlockSpec((I_tab, cap),
-                              lambda t, cot, dto: (0, 0))] * 2
-                if sparse else [])
-    rb_shape = ([jax.ShapeDtypeStruct((I_tab, cap), jnp.int32)] * 2
-                if sparse else [])
-    rb_args = (topics, counts) if sparse else ()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n // n_blk,),
-        in_specs=[
-            *(pl.BlockSpec((n_blk,), lambda t, cot, dto: (t,))
-              for _ in range(6)),                          # token stream
-            any_spec,                                      # n_td (HBM)
-            pl.BlockSpec((1, J, T), lambda t, cot, dto: (cot[t], 0, 0)),
-            pl.BlockSpec((T,), lambda t, cot, dto: (0,)),
-            *rb_specs,                                     # side tables
-        ],
-        out_specs=[
-            pl.BlockSpec((n_blk,), lambda t, cot, dto: (t,)),
-            any_spec,                                      # n_td' (HBM)
-            pl.BlockSpec((1, J, T), lambda t, cot, dto: (cot[t], 0, 0)),
-            pl.BlockSpec((T,), lambda t, cot, dto: (0,)),
-            pl.BlockSpec((2 * T,), lambda t, cot, dto: (0,)),
-            *rb_specs,                                     # side tables
-        ],
-        scratch_shapes=[pltpu.VMEM((doc_rows, T), jnp.int32),
-                        pltpu.SemaphoreType.DMA],
-    )
-    return pl.pallas_call(
-        functools.partial(_ragged_docs_kernel, T, n_blk, int(doc_rows),
-                          cap, sparse,
-                          float(alpha), float(beta), float(beta_bar)),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((I, T), jnp.int32),
-            jax.ShapeDtypeStruct((k, J, T), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((2 * T,), F32),
-            *rb_shape,
-        ],
-        interpret=interpret,
-    )(cell_of_tile, doc_tile_of, tok_doc, tok_wrd, tok_valid, tok_bound,
-      z, u, n_td, n_wt, n_t, *rb_args)
+    return _fused_call(
+        (cell_of_tile, doc_tile_of),
+        (tok_doc, tok_wrd, tok_valid, tok_bound, z, u),
+        n_td, n_wt, n_t, topics, counts,
+        grid=(tok_doc.shape[0] // n_blk,),
+        tok_index=lambda t, cot, dto: (t,),
+        nwt_index=lambda t, cot, dto: cot[t],
+        grid_pos=lambda pre: _slab_pos_1d(pre[1]),
+        cell_start_of=lambda pre: _ragged_cell_start(pre[0]),
+        doc_rows=int(doc_rows), alpha=alpha, beta=beta, beta_bar=beta_bar,
+        r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
+        interpret=interpret)

@@ -5,37 +5,85 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.prefix import topic_tile
 from repro.kernels.fused_sweep import rbucket
 from repro.kernels.fused_sweep.fused_sweep import (
     N_BLK, fused_sweep_cells_docs_pallas, fused_sweep_cells_pallas,
     fused_sweep_docs_pallas, fused_sweep_pallas,
     fused_sweep_ragged_docs_pallas, fused_sweep_ragged_pallas)
 
-# Soft ceiling for the compiled path: the count tables + tree + one token
-# tile must fit on-chip (~16 MiB/core, leave headroom for double buffers).
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# On-chip budgets of one TPU v5e TensorCore for a compiled call: 128 MiB
+# of VMEM, of which a kernel may claim up to the budget below (the rest
+# stays with the compiler), 16 MiB of it granted by default; 1 MiB of
+# SMEM, shared by scalar-prefetch maps, token tiles and the F+tree.
+VMEM_BUDGET_BYTES = 100 * 1024 * 1024
+VMEM_SCOPED_DEFAULT_BYTES = 16 * 1024 * 1024
+VMEM_HEADROOM_BYTES = 1024 * 1024
+SMEM_BUDGET_BYTES = 960 * 1024
 
 
 def _is_pow2(n: int) -> bool:
     return n >= 1 and (n & (n - 1)) == 0
 
 
+def topic_row_bytes(T: int) -> int:
+    """VMEM bytes of one 32-bit topic row: an ``(R, C)`` tile
+    (:func:`repro.core.prefix.topic_tile`) padded to whole ``(8, 128)``
+    vreg tiles — 4 KiB at T = 1024, where nothing is padded."""
+    R, C = topic_tile(T)
+    return 4 * (-(-R // 8) * 8) * (-(-C // 128) * 128)
+
+
 def fused_vmem_bytes(I: int, J: int, T: int, n_blk: int = N_BLK,
                      doc_rows: int = 0, r_cap: int = 0) -> int:
-    """VMEM-resident bytes of one fused sweep call (DESIGN.md §7).
+    """Scoped VMEM bytes one compiled fused sweep call may need
+    (DESIGN.md §7) — the worst case, in which XLA leaves every table in
+    HBM; XLA may instead pin a whole small table in VMEM, outside the
+    kernel's scoped allocation, and the kernel then needs less.
 
     Whole-shard mode (``doc_rows=0``) keeps the ``(I, T)`` doc-topic table
-    in VMEM twice (input + output buffers); doc-tiled mode keeps a single
-    ``(doc_rows, T)`` scratch slab and leaves the table in HBM.  Either
-    way one ``(J, T)`` word-topic block rides in+out, plus ``n_t``, the
-    F+tree output and the seven token-tile streams.  ``r_cap > 0``
-    (sparse r-mode) adds the two ``(I, r_cap)`` i32 side tables, each
-    riding in+out whole-VMEM (doc-tiled twins included — the tables are
-    never slabbed).
+    in VMEM twice (the input block and the output accumulator; their
+    index maps are constant, so neither is double-buffered); doc-tiled
+    mode keeps a single ``(doc_rows, T)`` scratch slab and leaves the
+    table in HBM.  The ``(J, T)`` word-topic block is paged per cell, so
+    Pallas double-buffers it on the way in and on the way out: four
+    copies.  ``r_cap > 0`` (sparse r-mode) adds the two ``(I, r_cap)`` i32
+    side tables, each in+out whole-VMEM (doc-tiled twins included — the
+    tables are never slabbed).  ``n_t``, the leaf mirror and the
+    compiler's own rows come to sixteen topic rows.  Token tiles live in
+    SMEM (:func:`fused_smem_bytes`), so ``n_blk`` does not enter.
     """
-    ntd = 4 * doc_rows * T if doc_rows > 0 else 2 * 4 * I * T
+    del n_blk
+    row = topic_row_bytes(T)
+    ntd = doc_rows * row if doc_rows > 0 else 2 * I * row
     rb = 4 * 4 * I * r_cap if r_cap > 0 else 0
-    return ntd + rb + 2 * 4 * (J * T + T) + 4 * 2 * T + 7 * 4 * n_blk
+    return ntd + rb + 4 * J * row + 16 * row
+
+
+def fused_smem_bytes(n_tiles: int, n_blk: int, T: int,
+                     n_maps: int) -> int:
+    """SMEM bytes of one compiled fused sweep call: ``n_maps``
+    scalar-prefetched per-tile maps (cell and/or slab), the seven token
+    tiles double-buffered, and the ``2T`` F+tree."""
+    return 4 * (n_maps * n_tiles + 2 * 7 * n_blk + 2 * T)
+
+
+def _vmem_limit(need: int, smem: int, what: str) -> int:
+    """The ``vmem_limit_bytes`` a compiled call needs (0: the compiler's
+    default scoped limit suffices); raises past either on-chip budget."""
+    if need > VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"{what} ({need / 2**20:.1f} MiB) exceeds the VMEM budget "
+            f"({VMEM_BUDGET_BYTES / 2**20:.0f} MiB); shard docs/vocab into "
+            f"smaller nomad cells, tile the doc axis (build_layout "
+            f"doc_tile) or use inner_mode='scan'")
+    if smem > SMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"{what} ({smem / 2**10:.0f} KiB of tile maps and token tiles) "
+            f"exceeds the SMEM budget; use larger tiles or split the "
+            f"stream into more calls")
+    need += VMEM_HEADROOM_BYTES
+    return need if need > VMEM_SCOPED_DEFAULT_BYTES else 0
 
 
 def _resolve_rmode(r_mode: str, r_cap, T: int):
@@ -103,7 +151,7 @@ def fused_sweep_tokens(tok_doc: jax.Array, tok_wrd: jax.Array,
                        r_mode: str = "dense", r_cap: int | None = None,
                        topics: jax.Array | None = None,
                        counts: jax.Array | None = None,
-                       n_blk: int = N_BLK, interpret: bool = True):
+                       n_blk: int = N_BLK, interpret: bool | None = None):
     """Fused word-by-word F+LDA sweep over an arbitrary-length token stream.
 
     Pads the stream to a multiple of ``n_blk`` with masked no-op tokens,
@@ -128,6 +176,7 @@ def fused_sweep_tokens(tok_doc: jax.Array, tok_wrd: jax.Array,
         raise ValueError(f"fused sweep needs a power-of-two T, got {T}")
     sparse, cap = _resolve_rmode(r_mode, r_cap, T)
     topics, counts = _side_tables(sparse, topics, counts, n_td, cap)
+    interpret = default_interpret() if interpret is None else bool(interpret)
     n = tok_doc.shape[0]
     if n == 0:
         out = (z, n_td, n_wt, n_t, jnp.zeros((2 * T,), jnp.float32))
@@ -138,19 +187,11 @@ def fused_sweep_tokens(tok_doc: jax.Array, tok_wrd: jax.Array,
             f"doc-tiled stream length {n} is not a whole number of "
             f"{n_blk}-token tiles (the slab map is per tile)")
     _check_doc_args(doc_tile_of, doc_rows, (n // n_blk,) if docs else None)
-    if not interpret:
-        # Whole-array in_specs AND out_specs each get their own VMEM buffer:
-        # two copies of every count table, one tree output, plus the six
-        # tiled input streams and the z output tile (doc-tiled: one slab
-        # scratch instead of the two n_td copies).
-        vmem = fused_vmem_bytes(I, J, T, n_blk,
-                                doc_rows if docs else 0,
-                                cap if sparse else 0)
-        if vmem > VMEM_BUDGET_BYTES:
-            raise ValueError(
-                f"fused sweep state ({vmem / 2**20:.1f} MiB) exceeds the "
-                f"VMEM budget; shard n_td/n_wt (nomad cells), tile the "
-                f"doc axis (build_layout doc_tile) or use backend='scan'")
+    vmem_limit = 0 if interpret else _vmem_limit(
+        fused_vmem_bytes(I, J, T, n_blk, doc_rows if docs else 0,
+                         cap if sparse else 0),
+        fused_smem_bytes(-(-n // n_blk), n_blk, T, int(docs)),
+        "fused sweep state")
 
     n_pad = -n % n_blk
     pad_i = lambda a: jnp.pad(a.astype(jnp.int32), (0, n_pad))
@@ -160,7 +201,8 @@ def fused_sweep_tokens(tok_doc: jax.Array, tok_wrd: jax.Array,
     u = jnp.pad(u.astype(jnp.float32), (0, n_pad))
 
     kw = dict(alpha=float(alpha), beta=float(beta),
-              beta_bar=float(beta_bar), n_blk=n_blk, interpret=interpret)
+              beta_bar=float(beta_bar), n_blk=n_blk,
+              interpret=interpret, vmem_limit=vmem_limit)
     kw["r_cap"] = cap
     if sparse:
         kw.update(topics=topics, counts=counts)
@@ -192,7 +234,7 @@ def fused_sweep_cells(tok_doc: jax.Array, tok_wrd: jax.Array,
                       r_mode: str = "dense", r_cap: int | None = None,
                       topics: jax.Array | None = None,
                       counts: jax.Array | None = None,
-                      n_blk: int = N_BLK, interpret: bool = True):
+                      n_blk: int = N_BLK, interpret: bool | None = None):
     """Fused F+LDA sweep over a batch of ``k`` padded cells in ONE kernel.
 
     This is the nomad hot path: ``tok_* / z / u`` are ``(k, L)`` — one row
@@ -226,6 +268,7 @@ def fused_sweep_cells(tok_doc: jax.Array, tok_wrd: jax.Array,
         raise ValueError(f"fused sweep needs a power-of-two T, got {T}")
     sparse, cap = _resolve_rmode(r_mode, r_cap, T)
     topics, counts = _side_tables(sparse, topics, counts, n_td, cap)
+    interpret = default_interpret() if interpret is None else bool(interpret)
     if tok_doc.shape[0] != k_total:
         raise ValueError(f"queue length mismatch: tokens have "
                          f"{tok_doc.shape[0]} cells, n_wt has {k_total} "
@@ -255,18 +298,11 @@ def fused_sweep_cells(tok_doc: jax.Array, tok_wrd: jax.Array,
     if k == 0 or L == 0:
         out = (z, n_td, n_wt, n_t, jnp.zeros((2 * T,), jnp.float32))
         return out + ((topics, counts) if sparse else ())
-    if not interpret:
-        # Whole-array n_td in+out (or one slab scratch when doc-tiled),
-        # ONE (J,T) word-topic block in+out (the queue is paged per
-        # cell), tree output, token tiles.
-        vmem = fused_vmem_bytes(I, J, T, n_blk, doc_rows if docs else 0,
-                                cap if sparse else 0)
-        if vmem > VMEM_BUDGET_BYTES:
-            raise ValueError(
-                f"fused cell-batch state ({vmem / 2**20:.1f} MiB) exceeds "
-                f"the VMEM budget; shard docs/vocab into smaller nomad "
-                f"cells, tile the doc axis (build_layout doc_tile) or use "
-                f"inner_mode='scan'")
+    vmem_limit = 0 if interpret else _vmem_limit(
+        fused_vmem_bytes(I, J, T, n_blk, doc_rows if docs else 0,
+                         cap if sparse else 0),
+        fused_smem_bytes(k * -(-L // n_blk), n_blk, T, int(docs)),
+        "fused cell-batch state")
 
     n_pad = -L % n_blk
     pad_i = lambda a: jnp.pad(a.astype(jnp.int32), ((0, 0), (0, n_pad)))
@@ -276,7 +312,8 @@ def fused_sweep_cells(tok_doc: jax.Array, tok_wrd: jax.Array,
     u = jnp.pad(u.astype(jnp.float32), ((0, 0), (0, n_pad)))
 
     kw = dict(alpha=float(alpha), beta=float(beta),
-              beta_bar=float(beta_bar), n_blk=n_blk, interpret=interpret)
+              beta_bar=float(beta_bar), n_blk=n_blk,
+              interpret=interpret, vmem_limit=vmem_limit)
     kw["r_cap"] = cap
     if sparse:
         kw.update(topics=topics, counts=counts)
@@ -309,7 +346,7 @@ def fused_sweep_ragged(tok_doc: jax.Array, tok_wrd: jax.Array,
                        r_mode: str = "dense", r_cap: int | None = None,
                        topics: jax.Array | None = None,
                        counts: jax.Array | None = None,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Fused F+LDA sweep over a ragged cell stream (the nomad hot path).
 
     ``tok_* / z / u`` are flat ``(S,)`` streams — a worker's whole
@@ -339,6 +376,7 @@ def fused_sweep_ragged(tok_doc: jax.Array, tok_wrd: jax.Array,
         raise ValueError(f"fused sweep needs a power-of-two T, got {T}")
     sparse, cap = _resolve_rmode(r_mode, r_cap, T)
     topics, counts = _side_tables(sparse, topics, counts, n_td, cap)
+    interpret = default_interpret() if interpret is None else bool(interpret)
     S = tok_doc.shape[0]
     if S % n_blk != 0 or cell_of_tile.shape[0] != S // n_blk:
         raise ValueError(
@@ -372,21 +410,15 @@ def fused_sweep_ragged(tok_doc: jax.Array, tok_wrd: jax.Array,
     if nt_ == 0 or nc == 0:
         out = (z, n_td, n_wt, n_t, jnp.zeros((2 * T,), jnp.float32))
         return out + ((topics, counts) if sparse else ())
-    if not interpret:
-        # Whole-array n_td in+out (or one slab scratch when doc-tiled),
-        # ONE (J,T) word-topic block in+out (the stream is paged per
-        # tile), tree output, token tiles.
-        vmem = fused_vmem_bytes(I, J, T, n_blk, doc_rows if docs else 0,
-                                cap if sparse else 0)
-        if vmem > VMEM_BUDGET_BYTES:
-            raise ValueError(
-                f"fused ragged-stream state ({vmem / 2**20:.1f} MiB) "
-                f"exceeds the VMEM budget; shard docs/vocab into smaller "
-                f"nomad cells, tile the doc axis (build_layout doc_tile) "
-                f"or use inner_mode='scan'")
+    vmem_limit = 0 if interpret else _vmem_limit(
+        fused_vmem_bytes(I, J, T, n_blk, doc_rows if docs else 0,
+                         cap if sparse else 0),
+        fused_smem_bytes(nt_, n_blk, T, 1 + int(docs)),
+        "fused ragged-stream state")
 
     kw = dict(alpha=float(alpha), beta=float(beta),
-              beta_bar=float(beta_bar), n_blk=n_blk, interpret=interpret)
+              beta_bar=float(beta_bar), n_blk=n_blk,
+              interpret=interpret, vmem_limit=vmem_limit)
     kw["r_cap"] = cap
     if sparse:
         kw.update(topics=topics, counts=counts)

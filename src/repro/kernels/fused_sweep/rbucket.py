@@ -4,7 +4,7 @@ The F+LDA conditional p = α·q + r has r_t = n_td·q_t supported on the
 document's |T_d| nonzero topics with |T_d| ≪ T (the paper's complexity
 argument for Alg. 3).  This module defines the **canonical r-draw** shared
 by every fused-sweep implementation (the Pallas kernels and the scan
-oracle): the r-term cumsum runs over a fixed-capacity compacted vector —
+oracle): the r-term prefix sum runs over a fixed-capacity compacted vector —
 the document's active topics in ascending order, zero-padded to a static
 capacity ``cap`` — instead of a dense ``(T,)`` vector.
 
@@ -19,18 +19,21 @@ Two ways to obtain the compacted vector, selected by ``r_mode``:
 Exactness argument: both modes operate on the *same* compacted vector —
 the side table's invariant is ``(topics, counts) == compact_row(n_td[d])``
 at every step, preserved by the integer-only increment/decrement — so the
-float ops of the draw (``cumsum`` over ``counts·q[topics]``) are performed
-on bit-identical inputs and the two chains are bit-equal by construction.
-Note the compacted cumsum is **not** bit-equal to a dense ``(T,)`` cumsum
-(XLA's scan is blocked/tree-associated, so dropping zeros reorders the
-partial sums); that is why *both* modes draw from the compacted vector.
+float ops of the draw (:func:`repro.core.prefix.prefix_sum` over
+``counts·q[topics]``) are performed on bit-identical inputs and the two
+chains are bit-equal by construction.  Note the compacted prefix sum is
+**not** bit-equal to a dense ``(T,)`` one (the log-step scan associates
+by position, so dropping zeros reorders the partial sums); that is why
+*both* modes draw from the compacted vector.  A compiled kernel builds
+that vector with :func:`pack`, which moves values and never adds them.
 For the same reason the capacity is chain-affecting: runs compared
 bit-for-bit must share ``cap`` (the default ``cap = T`` everywhere keeps
 cross-mode comparisons trivially paired).
 
 Zero padding is exact: pad slots are ``(topic 0, count 0)`` and contribute
-``0·q[0] = 0.0`` to the cumsum, and ``x + 0.0 == x`` for every finite f32,
-so the padded suffix never perturbs a partial sum.
+``0·q[0] = 0.0`` to the prefix sum, and a scan position only adds
+entries at or below it, so the padded suffix never perturbs a partial sum
+of the active prefix.
 
 Capacity bound: ``cap = min(T, max_d len(d))`` suffices — a document of
 ``n`` tokens holds at most ``n`` distinct topics, and at increment time the
@@ -44,10 +47,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.prefix import (flat_roll, prefix_sum, prefix_sum_tiles,
+                               topic_iota)
+
 F32 = jnp.float32
 
 __all__ = ["compact_row", "build_side_table", "decrement", "increment",
-           "r_cumsum", "pick"]
+           "r_cumsum", "pick", "pack"]
 
 
 def compact_row(row, cap: int):
@@ -114,15 +120,53 @@ def increment(topics, counts, t, valid):
 
 def r_cumsum(topics, counts, q):
     """Cumulative r-bucket masses over the compacted vector:
-    ``cumsum(counts · q[topics])`` (pad slots contribute exactly 0.0)."""
-    return jnp.cumsum(counts.astype(F32) * q[topics])
+    ``prefix_sum(counts · q[topics])`` (pad slots contribute exactly
+    0.0)."""
+    return prefix_sum(counts.astype(F32) * q[topics])
+
+
+def pack(vals, active, *, roll=None):
+    """Stable compaction of ``(R, C)`` topic tiles (row-major order)
+    without a scatter: the ``active`` entries of ``vals`` move to the
+    front in order, zeros behind.  Returns ``(packed, rank)`` with
+    ``rank`` each entry's count of active entries before it (its packed
+    slot when active).
+
+    A log-step network: every active entry must move down by ``s`` = the
+    inactive entries before it, and round ``b`` moves the entries whose
+    ``s`` has bit ``b`` set by ``2^b``.  ``s`` never decreases along the
+    order and exceeds no entry's own index, so no two entries ever meet
+    and none wraps.  Values are only selected, never added, so
+    ``packed`` is bit-identical to ``compact_row``'s gather for any
+    float.  ``roll`` is the axis roll (``prefix.tpu_roll`` inside a
+    Pallas body; ``jnp.roll`` by default)."""
+    kw = {} if roll is None else {"roll": roll}
+    R, C = vals.shape[-2:]
+    n = R * C
+    flat = topic_iota(vals.shape)
+    act = active.astype(jnp.int32)
+    rank = prefix_sum_tiles(act, **kw) - act
+    move = jnp.where(active, flat - rank, -1)      # -1: empty slot
+    v = jnp.where(active, vals, jnp.zeros_like(vals))
+    s = 1
+    while s < n:
+        leaving = (move >= 0) & ((move & s) != 0)
+        move_in = flat_roll(move, n - s, **kw)     # from slot + s
+        v_in = flat_roll(v, n - s, **kw)
+        arriving = (move_in >= 0) & ((move_in & s) != 0)
+        v = jnp.where(arriving, v_in,
+                      jnp.where(leaving, jnp.zeros_like(v), v))
+        move = jnp.where(arriving, move_in, jnp.where(leaving, -1, move))
+        s *= 2
+    return v, rank
 
 
 def pick(topics, counts, c, u_val):
-    """Zero-mass-aware LSearch on the compacted cumsum: the drawn slot is
-    ``#{c ≤ u_val}``, guarded to the last active slot so a boundary-rounded
-    ``u_val`` can never land on a zero-count pad (when ``u_val < c[-1]``
-    the guard is a no-op: pad entries all equal ``c[-1]``)."""
+    """Zero-mass-aware LSearch on the compacted prefix sum: the drawn slot
+    is ``#{c ≤ u_val}``, guarded to the last active slot so a
+    boundary-rounded ``u_val`` can never land on a zero-count pad (pad
+    entries hold the active total, re-associated, so they may sit an ulp
+    off the last active entry)."""
     m = jnp.sum((counts > 0).astype(jnp.int32))
     j_r = jnp.minimum(jnp.sum((c <= u_val).astype(jnp.int32)),
                       jnp.maximum(m - 1, 0))

@@ -186,7 +186,7 @@ def fused_sweep_ragged_ref(tok_doc, tok_wrd, tok_valid, tok_bound, z, u,
     if nt_ == 0 or nc == 0:
         out = (z[:0], n_td, nwt_sub[:0], n_t, jnp.zeros((2 * T,), F32))
         return out + ((topics, counts) if sparse else ())
-    cell_tok = jnp.repeat(cot, n_blk, total_repeat_length=nt_ * n_blk)
+    cell_tok = jnp.broadcast_to(cot[:, None], (nt_, n_blk)).reshape(-1)
     wrd_flat = cell_tok * J + sub(tok_wrd)
     out = fused_sweep_ref(
         sub(tok_doc), wrd_flat, sub(tok_valid), sub(tok_bound),

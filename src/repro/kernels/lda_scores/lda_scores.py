@@ -43,7 +43,7 @@ def _kernel(alpha: float, beta: float, beta_bar: float,
 def lda_scores_pallas(n_td_rows: jax.Array, n_wt_rows: jax.Array,
                       n_t: jax.Array, u01: jax.Array, *,
                       alpha: float, beta: float, beta_bar: float,
-                      interpret: bool = True):
+                      interpret: bool):
     n, T = n_td_rows.shape
     grid = (n // N_BLK,)
     return pl.pallas_call(

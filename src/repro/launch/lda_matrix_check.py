@@ -1,7 +1,7 @@
 """Distributed sweep exactness matrix (run as a subprocess).
 
 Usage:  python -m repro.launch.lda_matrix_check [n_devices] [n_sweeps] \
-            [subset]
+            [subset] [shard]
 
 One faked-multi-device process sweeps every combination of
 ``sync_mode`` ∈ {stoken, stale, allreduce} × ``inner_mode`` ∈ {scan, fused,
@@ -46,6 +46,11 @@ twin and (ungrouped only) the sparse-r twin — and reports each layout's ``ntd_
 (``repro.kernels.fused_sweep.fused_vmem_bytes``) so CI prints the slab
 VMEM number; the full matrix stays behind the tier-1 ``slow`` marker.
 
+``shard = "i/n"`` (argv[4]) runs only the (B × doc_tile, sync_mode,
+inner_mode) groups whose index is ``i`` mod ``n``.  Every cross-run
+comparison stays inside one group, so ``n`` shards run as ``n``
+processes check exactly what one process checks.
+
 Prints one JSON report: ``{"combos": [...], "all_exact": bool}``.
 """
 import json
@@ -59,6 +64,8 @@ def main() -> None:
     subset = sys.argv[3] if len(sys.argv) > 3 else "full"
     if subset not in ("full", "smoke"):
         raise SystemExit(f"unknown subset {subset!r} (full|smoke)")
+    shard, n_shards = map(int, (sys.argv[4] if len(sys.argv) > 4
+                                else "0/1").split("/"))
 
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_dev} "
@@ -141,6 +148,7 @@ def main() -> None:
         ring_modes = ("barrier", "pipelined")
 
     slab_report = []
+    group = -1
     for b_mult, dt in cases:
         layouts = layouts_for(b_mult, dt)
         if dt:
@@ -156,6 +164,9 @@ def main() -> None:
                 })
         for sync_mode in sync_modes:
             for inner_mode in inner_modes:
+                group += 1
+                if group % n_shards != shard:
+                    continue
                 per_run = {}
                 for kind in ("dense", "ragged"):
                     layout = layouts[kind]
@@ -212,7 +223,8 @@ def main() -> None:
                 for f in ("z", "n_wt", "n_t"))
         for c in combos)
     print(json.dumps({"n_devices": n_dev, "n_sweeps": n_sweeps,
-                      "subset": subset, "combos": combos,
+                      "subset": subset, "shard": f"{shard}/{n_shards}",
+                      "combos": combos,
                       "slab_vmem": slab_report, "all_exact": all_exact}))
 
 

@@ -235,8 +235,8 @@ class _Buffer:
 # Engine
 # ---------------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("sweeps", "inner_mode", "interpret"))
-def _theta_kernel(word_ids, valid, phi, alpha, doc_keys, sweeps,
-                  inner_mode="scan", interpret=True):
+def _theta_kernel(word_ids, valid, phi, alpha, doc_keys, sweeps, *,
+                  inner_mode, interpret):
     if inner_mode == "fused":
         n_td = fold_in_fused(word_ids, valid, phi, alpha, doc_keys,
                              sweeps, interpret=interpret)

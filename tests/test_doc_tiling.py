@@ -294,7 +294,8 @@ class TestVmemCeiling:
                                                fused_vmem_bytes)
         from repro.kernels.fused_sweep.ops import VMEM_BUDGET_BYTES
         from repro.kernels.fused_sweep.ref import fused_sweep_ref
-        I, T, doc_rows, n_blk = 2000, 1024, 256, 32
+        # a shard whose two whole-table copies outgrow the VMEM budget
+        I, T, doc_rows, n_blk = 16384, 1024, 256, 32
         *args, dto = self._big_stream(I=I, T=T, doc_rows=doc_rows,
                                       n_blk=n_blk)
         kw = dict(alpha=50.0 / T, beta=0.01, beta_bar=0.01 * 8)
@@ -352,3 +353,26 @@ class TestNomadDocTiling:
         with pytest.raises(ValueError, match="doc_tile"):
             NomadLDA(mesh=mesh, ring_axes=("worker",), layout=lay,
                      alpha=1.0, beta=0.01, doc_tile=4)
+
+
+def test_padding_rows_stay_inside_the_slab():
+    """Padding tokens carry doc 0, outside every slab but the first; the
+    slab accessors must clamp them into the slab (on the chip an
+    unclamped row addresses VMEM outside the buffer, which no interpreter
+    reports), while valid rows map exactly."""
+    from repro.kernels.fused_sweep.fused_sweep import _slab_accessors
+    seen = []
+
+    class Slab:
+        def __getitem__(self, i):
+            seen.append(int(i))
+
+        def __setitem__(self, i, row):
+            seen.append(int(i))
+
+    load, store = _slab_accessors(Slab(), 3, 8)
+    load(0)
+    store(0, None)
+    load(3 * 8 + 5)
+    store(3 * 8 + 7, None)
+    assert seen == [0, 0, 5, 7]

@@ -20,8 +20,10 @@ import pytest
 
 from repro.core.heldout import doc_fold_key, fold_in, fold_in_batch
 from repro.kernels.fold_in import (fold_in_draws, fold_in_fused,
-                                   fold_in_kernel_ref, fold_in_vmem_bytes)
-from repro.kernels.fused_sweep.ops import VMEM_BUDGET_BYTES
+                                   fold_in_kernel_ref, fold_in_smem_bytes,
+                                   fold_in_vmem_bytes)
+from repro.kernels.fused_sweep.ops import (SMEM_BUDGET_BYTES,
+                                           VMEM_SCOPED_DEFAULT_BYTES)
 
 J, T = 31, 8
 ALPHA = 0.375
@@ -123,13 +125,15 @@ class TestFoldInWrapper:
     def test_vmem_budget_guard_compiled_only(self, phi):
         w, v = _batch(0, [2, 2], 4)
         dk = _keys(jax.random.key(0), 2)
-        # estimate is monotone and the guard trips only on the compiled
-        # path; interpret mode must not consult it
-        assert fold_in_vmem_bytes(4, T, 2) < VMEM_BUDGET_BYTES
-        big_L = VMEM_BUDGET_BYTES  # sweeps·L alone blows the budget
-        assert fold_in_vmem_bytes(big_L, T, 2) > VMEM_BUDGET_BYTES
+        # the token rows live in SMEM: their estimate grows with L and the
+        # guard trips only on the compiled path; interpret mode must not
+        # consult it
+        assert fold_in_vmem_bytes(4, T, 2) < VMEM_SCOPED_DEFAULT_BYTES
+        assert fold_in_smem_bytes(4) < SMEM_BUDGET_BYTES
+        big_L = SMEM_BUDGET_BYTES  # the token rows alone blow the budget
+        assert fold_in_smem_bytes(big_L) > SMEM_BUDGET_BYTES
         wide = jnp.zeros((1, big_L), jnp.int32)
-        with pytest.raises(ValueError, match="VMEM budget"):
+        with pytest.raises(ValueError, match="SMEM budget"):
             fold_in_fused(wide, wide.astype(bool), phi, ALPHA, dk[:1],
                           2, interpret=False)
 

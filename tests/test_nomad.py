@@ -5,6 +5,7 @@ runs in-process; multi-device rings run in subprocesses with
 XLA_FLAGS=--xla_force_host_platform_device_count so the main test process
 keeps its single real device (per the dry-run isolation rule).
 """
+import concurrent.futures
 import json
 import os
 import subprocess
@@ -392,10 +393,16 @@ class TestMultiDevice:
         dense one in every cell, every doc-tiled (slab-paged) run
         bit-equal to the untiled run over the same grouped layout, and
         every sparse-r run bit-equal to its dense-r twin."""
-        # 420 combos (the r_mode axis grew the matrix 252 -> 420) need
-        # more than the default 900 s budget on a loaded CPU host
-        rep = _run_module("repro.launch.lda_matrix_check", 8, 2,
-                          timeout=2700)
+        # 420 combos (the r_mode axis grew the matrix 252 -> 420) take
+        # ~10 min in one process on a CPU host, so they run as four
+        # shards side by side (each group's comparisons stay in its shard)
+        with concurrent.futures.ThreadPoolExecutor(4) as ex:
+            reps = list(ex.map(
+                lambda i: _run_module("repro.launch.lda_matrix_check", 8,
+                                      2, "full", f"{i}/4", timeout=2700),
+                range(4)))
+        rep = {"combos": [c for r in reps for c in r["combos"]],
+               "all_exact": all(r["all_exact"] for r in reps)}
         assert len(rep["combos"]) == 420
         assert {c["ring_mode"] for c in rep["combos"]} == \
             {"barrier", "pipelined"}
