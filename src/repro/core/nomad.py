@@ -75,8 +75,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.compat import shard_map
-from repro.data.sharding import NomadLayout
+from repro.data.sharding import NomadLayout, half_queue_split
 
 __all__ = ["NomadLDA", "nomad_sweep_fn"]
 
@@ -485,8 +486,6 @@ def nomad_sweep_fn(mesh: Mesh, ring_axes: Sequence[str], *,
     was built for (``NomadLayout.doc_blk``; ragged pages at its own
     ``tile``).
     """
-    from repro.data.sharding import half_queue_split
-
     sizes = tuple(int(mesh.shape[ax]) for ax in ring_axes)
     W = int(np.prod(sizes))
     if B % W != 0 or B < W:
@@ -848,9 +847,29 @@ class NomadLDA:
         ring = tuple(self.ring_axes)
         self._sh_tok = NamedSharding(self.mesh, P(ring, None, None))
         self._sh_rep = NamedSharding(self.mesh, P())
+        # What one sweep does, for its span: the work of every (round,
+        # worker, half), the fused-kernel calls each chip runs, and each
+        # device's ring position.
+        self._work = lay.half_work()
+        coords = np.indices(self.mesh.devices.shape)
+        pos = np.ravel_multi_index(
+            [coords[self.mesh.axis_names.index(ax)] for ax in self.ring_axes],
+            [self.mesh.shape[ax] for ax in self.ring_axes])
+        self._worker_of = {int(d.id): int(w) for d, w in
+                           zip(self.mesh.devices.flat, pos.flat)}
+        split = half_queue_split(lay.k) > 0 and (
+            lay.kind != "ragged" or lay.tile_split > 0)
+        per_round = 2 if self.ring_mode == "pipelined" and split else 1
+        self._calls = per_round * W if self.inner_mode == "fused" else 0
+        self._tokens, self._rebuilds = (
+            int(n) for n in self._work[..., :2].sum((0, 1, 2)))
 
     # -- state construction --------------------------------------------------
     def init_arrays(self, seed: int = 0):
+        with obs.span("nomad.init_arrays"):
+            return self._init_arrays(seed)
+
+    def _init_arrays(self, seed: int):
         lay = self.layout
         rng = np.random.default_rng(seed)
         # Initial assignments are drawn in canonical token order — the same
@@ -897,19 +916,31 @@ class NomadLDA:
         return arrays
 
     def sweep(self, arrays: dict, seed: int) -> dict:
+        """One sweep, dispatched and not waited for.  Its span
+        ``nomad.sweep`` times argument assembly and dispatch and carries
+        the seed, the fused-kernel ``calls`` each chip runs, the
+        layout's per-(round, worker, half) ``work``
+        (:meth:`NomadLayout.half_work`) and ``worker_of``, each device
+        id's ring position."""
         lay = self.layout
-        args = (arrays["tok_doc"], arrays["tok_wrd"], arrays["tok_valid"],
-                arrays["tok_bound"], arrays["z"], arrays["n_td"],
-                arrays["n_wt"], arrays["n_t"], jnp.int32(seed))
-        if lay.kind == "ragged":
-            args += (arrays["cell_of_tile"], arrays["tok_slot"])
-        elif lay.doc_tile > 0:
-            args += (arrays["tok_slot"],)
-        if lay.doc_tile > 0:
-            args += (arrays["doc_tile_of"],)
-        if self.r_mode == "sparse":
-            args += (arrays["rb_topics"], arrays["rb_counts"])
-        res = self._sweep(*args)
+        with obs.span("nomad.sweep", seed=int(seed), calls=self._calls,
+                      work=self._work, worker_of=self._worker_of):
+            args = (arrays["tok_doc"], arrays["tok_wrd"],
+                    arrays["tok_valid"], arrays["tok_bound"], arrays["z"],
+                    arrays["n_td"], arrays["n_wt"], arrays["n_t"],
+                    jnp.int32(seed))
+            if lay.kind == "ragged":
+                args += (arrays["cell_of_tile"], arrays["tok_slot"])
+            elif lay.doc_tile > 0:
+                args += (arrays["tok_slot"],)
+            if lay.doc_tile > 0:
+                args += (arrays["doc_tile_of"],)
+            if self.r_mode == "sparse":
+                args += (arrays["rb_topics"], arrays["rb_counts"])
+            res = self._sweep(*args)
+        obs.count("nomad.sweeps")
+        obs.count("nomad.tokens", self._tokens)
+        obs.count("nomad.rebuilds", self._rebuilds)
         out = dict(arrays)
         out.update(z=res[0], n_td=res[1], n_wt=res[2], n_t=res[3])
         if self.r_mode == "sparse":
@@ -1169,14 +1200,17 @@ class NomadLDA:
                 if on_sweep is not None:
                     on_sweep(s, arrays)
                 if publish_every and (s + 1) % publish_every == 0:
-                    jax.block_until_ready(arrays["n_t"])
-                    if "drop" not in fault.fire("trainer.publish", index=s):
-                        on_publish(
-                            self.export_phi_snapshot(arrays, sweep=s + 1))
+                    with obs.span("nomad.publish", sweep=s):
+                        jax.block_until_ready(arrays["n_t"])
+                        if "drop" not in fault.fire("trainer.publish",
+                                                    index=s):
+                            on_publish(self.export_phi_snapshot(
+                                arrays, sweep=s + 1))
                 if (self.checkpoint_every
                         and (s + 1) % self.checkpoint_every == 0):
-                    jax.block_until_ready(arrays["n_t"])
-                    self.save_checkpoint(self.checkpoint_path, arrays,
-                                         next_seed=s + 1)
+                    with obs.span("nomad.checkpoint", sweep=s):
+                        jax.block_until_ready(arrays["n_t"])
+                        self.save_checkpoint(self.checkpoint_path, arrays,
+                                             next_seed=s + 1)
                 fault.fire("trainer.sweep", index=s)
         return arrays, n_sweeps

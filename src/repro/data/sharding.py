@@ -58,6 +58,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import obs
 from repro.data.corpus import Corpus
 
 __all__ = ["NomadLayout", "counts_from_layout", "lpt_assign",
@@ -428,21 +429,42 @@ class NomadLayout:
         return out
 
     def half_loads(self) -> np.ndarray:
-        """(W_rounds, W, 2) token loads of the two pipelined half-queues.
+        """(W_rounds, W, 2) token loads of the two pipelined half-queues:
+        the real-token column of :meth:`half_work`."""
+        return self.half_work()[..., 0]
 
-        Entry ``[r, w]`` is ``(first-half, second-half)`` token counts of
-        the queue worker ``w`` sweeps in ring round ``r`` when split at
-        :func:`half_queue_split`.  With ``k < 2`` the first column is all
+    def half_work(self) -> np.ndarray:
+        """(W_rounds, W, 2, 3) work of the two pipelined half-queues.
+
+        Entry ``[r, w, h]`` is ``(real tokens, F+tree rebuilds, stream
+        slots)`` of half ``h`` of the queue worker ``w`` sweeps in ring
+        round ``r`` (chunk ``(w + r) % W``) when split at
+        :func:`half_queue_split`.  Rebuilds are the valid tokens with
+        ``tok_bound`` set, the tokens on which the fused kernel rebuilds
+        its tree; slots count padding too, as the kernel iterates them
+        (the ragged halves' tile ranges, or the dense cells' rows padded
+        to the kernel's grid step).  With ``k < 2`` the first half is all
         zero (degenerate split)."""
         W, k = self.W, self.k
         k0 = half_queue_split(k)
-        out = np.zeros((W, W, 2), np.int64)
-        for r in range(W):
-            for w in range(W):
-                c = (w + r) % W
-                q = self.cell_sizes[w, c * k:(c + 1) * k]
-                out[r, w] = (q[:k0].sum(), q[k0:].sum())
-        return out
+        rebuild = self.tok_valid & self.tok_bound
+        per_chunk = np.zeros((W, W, 2, 3), np.int64)     # [w, chunk, half]
+        if self.kind == "ragged":
+            cut = (self.tile_split if k0 else 0) * self.tile
+            for h, sl in enumerate((np.s_[..., :cut], np.s_[..., cut:])):
+                for col, a in enumerate((self.tok_valid, rebuild)):
+                    per_chunk[..., h, col] = np.count_nonzero(a[sl], axis=-1)
+            per_chunk[..., :, 2] = (cut, self.stream_len - cut)
+        else:
+            row = self.total_tiles // (W * self.B) * (self.doc_blk
+                                                      or _dense_doc_blk())
+            for col, a in enumerate((self.tok_valid, rebuild)):
+                cells = np.count_nonzero(a, axis=-1).reshape(W, W, k)
+                per_chunk[..., 0, col] = cells[..., :k0].sum(-1)
+                per_chunk[..., 1, col] = cells[..., k0:].sum(-1)
+            per_chunk[..., :, 2] = (k0 * row, (k - k0) * row)
+        w = np.arange(W)
+        return np.stack([per_chunk[w, (w + r) % W] for r in range(W)])
 
 
 def counts_from_layout(lay: NomadLayout, z: np.ndarray, T: int):
@@ -787,64 +809,70 @@ def build_layout(corpus: Corpus, *, n_workers: int, T: int,
     identical layout from an out-of-core shard store; both feed the same
     :class:`_LayoutAssembler` so the outputs are byte-for-byte equal.
     """
-    B = n_workers if n_blocks is None else n_blocks
-    W = n_workers
-    _validate_build_args(W, B, layout, doc_tile, doc_blk)
+    with obs.span("layout.build") as attrs:
+        B = n_workers if n_blocks is None else n_blocks
+        W = n_workers
+        _validate_build_args(W, B, layout, doc_tile, doc_blk)
 
-    def freq_w(doc_assign):
-        fw = np.zeros((W, corpus.num_words), np.int64)
-        np.add.at(fw, (doc_assign[corpus.doc_ids], corpus.word_ids), 1)
-        return fw
+        def freq_w(doc_assign):
+            fw = np.zeros((W, corpus.num_words), np.int64)
+            np.add.at(fw, (doc_assign[corpus.doc_ids], corpus.word_ids), 1)
+            return fw
 
-    doc_assign, word_assign = _plan_partition(
-        corpus.doc_lengths(), corpus.word_freqs(), W=W, B=B,
-        balance=balance, freq_w=freq_w)
-    (doc_of_worker, doc_local, word_of_block, word_local,
-     I_max, J_max) = _local_maps(doc_assign, word_assign, W, B)
+        doc_assign, word_assign = _plan_partition(
+            corpus.doc_lengths(), corpus.word_freqs(), W=W, B=B,
+            balance=balance, freq_w=freq_w)
+        (doc_of_worker, doc_local, word_of_block, word_local,
+         I_max, J_max) = _local_maps(doc_assign, word_assign, W, B)
 
-    # Cell grid: sort tokens by (worker, block[, doc group], word id).
-    tw = doc_assign[corpus.doc_ids]
-    tb = word_assign[corpus.word_ids]
-    if doc_tile is not None:
-        dt = int(doc_tile)
-        n_doc_tiles = max(-(-I_max // dt), 1)
-        g_tok = (doc_local[corpus.doc_ids] // dt).astype(np.int64)
-        order = np.lexsort((corpus.word_ids, g_tok, tb, tw)).astype(np.int64)
-        sg = g_tok[order]
-    else:
-        dt, n_doc_tiles, sg = 0, 1, None
-        order = np.lexsort((corpus.word_ids, tb, tw)).astype(np.int64)
-    sw, sb = tw[order], tb[order]
-    sdoc, swrd = corpus.doc_ids[order], corpus.word_ids[order]
+        # Cell grid: sort tokens by (worker, block[, doc group], word id).
+        tw = doc_assign[corpus.doc_ids]
+        tb = word_assign[corpus.word_ids]
+        if doc_tile is not None:
+            dt = int(doc_tile)
+            n_doc_tiles = max(-(-I_max // dt), 1)
+            g_tok = (doc_local[corpus.doc_ids] // dt).astype(np.int64)
+            order = np.lexsort((corpus.word_ids, g_tok, tb,
+                                tw)).astype(np.int64)
+            sg = g_tok[order]
+        else:
+            dt, n_doc_tiles, sg = 0, 1, None
+            order = np.lexsort((corpus.word_ids, tb, tw)).astype(np.int64)
+        sw, sb = tw[order], tb[order]
+        sdoc, swrd = corpus.doc_ids[order], corpus.word_ids[order]
 
-    cell_sizes = np.zeros((W, B), np.int64)
-    np.add.at(cell_sizes, (sw, sb), 1)
-    seg_counts = None
-    if dt:
-        seg_counts = np.zeros((W * B, n_doc_tiles), np.int64)
-        np.add.at(seg_counts, (sw.astype(np.int64) * B + sb, sg), 1)
-    gran, tile = _resolve_gran(layout, dt, doc_blk, tile, cell_sizes)
+        cell_sizes = np.zeros((W, B), np.int64)
+        np.add.at(cell_sizes, (sw, sb), 1)
+        seg_counts = None
+        if dt:
+            seg_counts = np.zeros((W * B, n_doc_tiles), np.int64)
+            np.add.at(seg_counts, (sw.astype(np.int64) * B + sb, sg), 1)
+        gran, tile = _resolve_gran(layout, dt, doc_blk, tile, cell_sizes)
 
-    geom = _build_geometry(cell_sizes, seg_counts, layout=layout, W=W, B=B,
-                           dt=dt, gran=gran, n_doc_tiles=n_doc_tiles,
-                           tile=tile)
-    asm = _LayoutAssembler(geom, sw.shape[0])
-    w_bounds = np.searchsorted(sw, np.arange(W + 1))
-    for w in range(W):
-        lo, hi = int(w_bounds[w]), int(w_bounds[w + 1])
-        asm.add_worker(w, sb[lo:hi], doc_local[sdoc[lo:hi]],
-                       word_local[swrd[lo:hi]], swrd[lo:hi],
-                       sg[lo:hi] if dt else None)
+        geom = _build_geometry(cell_sizes, seg_counts, layout=layout, W=W,
+                               B=B, dt=dt, gran=gran,
+                               n_doc_tiles=n_doc_tiles, tile=tile)
+        asm = _LayoutAssembler(geom, sw.shape[0])
+        w_bounds = np.searchsorted(sw, np.arange(W + 1))
+        for w in range(W):
+            lo, hi = int(w_bounds[w]), int(w_bounds[w + 1])
+            asm.add_worker(w, sb[lo:hi], doc_local[sdoc[lo:hi]],
+                           word_local[swrd[lo:hi]], swrd[lo:hi],
+                           sg[lo:hi] if dt else None)
 
-    # Sparse r-bucket capacity (rbucket module docstring): a document of n
-    # tokens holds ≤ min(T, n) distinct topics, and at increment time one
-    # token is unassigned, so min(T, max doc length) slots always suffice.
-    r_cap = max(1, min(T, int(corpus.doc_lengths().max(initial=1))))
-    return asm.finish(
-        T=T, num_words=corpus.num_words, doc_of_worker=doc_of_worker,
-        word_of_block=word_of_block, I_max=I_max, J_max=J_max,
-        doc_assign=doc_assign, word_assign=word_assign,
-        cell_sizes=cell_sizes, r_cap=r_cap)
+        # Sparse r-bucket capacity (rbucket module docstring): a document
+        # of n tokens holds ≤ min(T, n) distinct topics, and at increment
+        # time one token is unassigned, so min(T, max doc length) slots
+        # always suffice.
+        r_cap = max(1, min(T, int(corpus.doc_lengths().max(initial=1))))
+        lay = asm.finish(
+            T=T, num_words=corpus.num_words, doc_of_worker=doc_of_worker,
+            word_of_block=word_of_block, I_max=I_max, J_max=J_max,
+            doc_assign=doc_assign, word_assign=word_assign,
+            cell_sizes=cell_sizes, r_cap=r_cap)
+        attrs.update(zip(("tokens", "rebuilds", "slots"),
+                         (int(n) for n in lay.half_work().sum((0, 1, 2)))))
+    return lay
 
 
 def _running_count(groups: np.ndarray) -> np.ndarray:

@@ -127,6 +127,7 @@ def fold_in_pallas(word_ids: jax.Array, valid: jax.Array, z0: jax.Array,
                         pltpu.VMEM(tile, F32),             # φ row
                         pltpu.SemaphoreType.DMA],
         interpret=interpret,
+        name="fold_in",
     )(row3(word_ids), row3(valid), row3(z0),
       u.reshape(D * sweeps, 1, L), alpha, phi.reshape(J, *tile))
     return out.reshape(D, T)

@@ -56,4 +56,5 @@ def ftree_sample_pallas(F: jax.Array, u01: jax.Array,
         out_specs=pl.BlockSpec((N_BLK,), lambda b: (b,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
+        name="ftree_sample",
     )(F, u01)

@@ -48,4 +48,5 @@ def ftree_update_pallas(F: jax.Array, ts: jax.Array, deltas: jax.Array,
         out_specs=pl.BlockSpec((two_t,), lambda: (0,)),
         out_shape=jax.ShapeDtypeStruct((two_t,), F.dtype),
         interpret=interpret,
+        name="ftree_update",
     )(F, ts, deltas)

@@ -487,8 +487,8 @@ def _whole(*shape):
 def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
                 grid, tok_index, nwt_index, grid_pos, cell_start_of,
                 doc_rows, alpha, beta, beta_bar, r_cap, n_blk, vmem_limit,
-                interpret):
-    """One fused-sweep ``pallas_call``.
+                interpret, name):
+    """One fused-sweep ``pallas_call``, named ``name``.
 
     The six token streams (any shape, ``n_blk`` tokens per tile) ride as
     ``(n_tiles, 1, n_blk)`` SMEM blocks — last two dims whole, so any
@@ -555,6 +555,7 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
         # doc-tiled: n_td's HBM input is its output, paged in place
         input_output_aliases={len(prefetch) + 6: 1} if docs else {},
         interpret=interpret,
+        name=name,
     )(*prefetch, *toks, n_td, n_wt, n_t,
       *((topics, counts) if sparse else ()))
     z, n_td, n_wt, n_t, f_int, f_leaf = out[:6]
@@ -565,7 +566,7 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
 
 
 _STATIC = ("alpha", "beta", "beta_bar", "n_blk", "r_cap", "interpret",
-           "vmem_limit")
+           "vmem_limit", "name")
 
 
 def _first_last_1d(pre):
@@ -612,7 +613,8 @@ def fused_sweep_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                        counts: jax.Array | None = None, *,
                        alpha: float, beta: float, beta_bar: float,
                        r_cap: int = 0, n_blk: int = N_BLK,
-                       vmem_limit: int = 0, interpret: bool):
+                       vmem_limit: int = 0, interpret: bool,
+                       name: str = "fused_sweep"):
     """One fused F+LDA sweep over a padded token stream.
 
     Shapes: tok_* / z / u are (N,) with N % n_blk == 0; n_td (I, T) i32;
@@ -632,7 +634,7 @@ def fused_sweep_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
         grid_pos=lambda pre: _first_last_1d(pre) + (None, None),
         cell_start_of=lambda pre: None, doc_rows=0, alpha=alpha, beta=beta,
         beta_bar=beta_bar, r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
-        interpret=interpret)
+        interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -645,7 +647,8 @@ def fused_sweep_cells_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
                              counts: jax.Array | None = None, *,
                              alpha: float, beta: float, beta_bar: float,
                              r_cap: int = 0, n_blk: int = N_BLK,
-                             vmem_limit: int = 0, interpret: bool):
+                             vmem_limit: int = 0, interpret: bool,
+                             name: str = "fused_sweep_cells"):
     """One fused F+LDA sweep over a batch of k cells (a nomad block queue).
 
     Shapes: tok_* / z / u are (k, L) with L % n_blk == 0; n_td (I, T) i32
@@ -665,7 +668,7 @@ def fused_sweep_cells_pallas(tok_doc: jax.Array, tok_wrd: jax.Array,
         grid_pos=lambda pre: _first_last_cells(pre) + (None, None),
         cell_start_of=lambda pre: pl.program_id(1) == 0, doc_rows=0,
         alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
-        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -679,7 +682,8 @@ def fused_sweep_ragged_pallas(cell_of_tile: jax.Array,
                               counts: jax.Array | None = None, *,
                               alpha: float, beta: float, beta_bar: float,
                               r_cap: int = 0, n_blk: int,
-                              vmem_limit: int = 0, interpret: bool):
+                              vmem_limit: int = 0, interpret: bool,
+                              name: str = "fused_sweep_ragged"):
     """One fused F+LDA sweep over a ragged cell stream (a nomad queue).
 
     Shapes: tok_* / z / u are (S,) with ``S = n_tiles·n_blk``;
@@ -699,7 +703,7 @@ def fused_sweep_ragged_pallas(cell_of_tile: jax.Array,
         grid_pos=lambda pre: _first_last_1d(pre) + (None, None),
         cell_start_of=lambda pre: _ragged_cell_start(pre[0]), doc_rows=0,
         alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
-        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
@@ -714,7 +718,8 @@ def fused_sweep_docs_pallas(doc_tile_of: jax.Array,
                             alpha: float, beta: float, beta_bar: float,
                             doc_rows: int, r_cap: int = 0,
                             n_blk: int = N_BLK, vmem_limit: int = 0,
-                            interpret: bool):
+                            interpret: bool,
+                            name: str = "fused_sweep_docs"):
     """Doc-tiled twin of :func:`fused_sweep_pallas`.
 
     ``doc_tile_of`` is the (n // n_blk,) per-tile slab map; ``n_td`` rows
@@ -731,7 +736,7 @@ def fused_sweep_docs_pallas(doc_tile_of: jax.Array,
         nwt_index=None, grid_pos=lambda pre: _slab_pos_1d(pre[0]),
         cell_start_of=lambda pre: None, doc_rows=int(doc_rows),
         alpha=alpha, beta=beta, beta_bar=beta_bar, r_cap=r_cap,
-        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret)
+        n_blk=n_blk, vmem_limit=vmem_limit, interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
@@ -746,7 +751,8 @@ def fused_sweep_cells_docs_pallas(doc_tile_of: jax.Array,
                                   alpha: float, beta: float, beta_bar: float,
                                   doc_rows: int, r_cap: int = 0,
                                   n_blk: int = N_BLK, vmem_limit: int = 0,
-                                  interpret: bool):
+                                  interpret: bool,
+                                  name: str = "fused_sweep_cells_docs"):
     """Doc-tiled twin of :func:`fused_sweep_cells_pallas`; ``doc_tile_of``
     is the (k, L // n_blk) per-(cell, tile) slab map."""
     k, L = tok_doc.shape
@@ -760,7 +766,7 @@ def fused_sweep_cells_docs_pallas(doc_tile_of: jax.Array,
         cell_start_of=lambda pre: pl.program_id(1) == 0,
         doc_rows=int(doc_rows), alpha=alpha, beta=beta, beta_bar=beta_bar,
         r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
-        interpret=interpret)
+        interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("doc_rows",))
@@ -777,7 +783,8 @@ def fused_sweep_ragged_docs_pallas(cell_of_tile: jax.Array,
                                    alpha: float, beta: float,
                                    beta_bar: float, doc_rows: int,
                                    r_cap: int = 0, n_blk: int,
-                                   vmem_limit: int = 0, interpret: bool):
+                                   vmem_limit: int = 0, interpret: bool,
+                                   name: str = "fused_sweep_ragged_docs"):
     """Doc-tiled twin of :func:`fused_sweep_ragged_pallas`: two
     scalar-prefetch maps drive the paging — ``cell_of_tile`` pages the
     word-topic block (BlockSpec window, visited once per cell) and
@@ -794,4 +801,4 @@ def fused_sweep_ragged_docs_pallas(cell_of_tile: jax.Array,
         cell_start_of=lambda pre: _ragged_cell_start(pre[0]),
         doc_rows=int(doc_rows), alpha=alpha, beta=beta, beta_bar=beta_bar,
         r_cap=r_cap, n_blk=n_blk, vmem_limit=vmem_limit,
-        interpret=interpret)
+        interpret=interpret, name=name)

@@ -132,6 +132,17 @@ def _pad_doc_slabs(n_td, doc_rows: int):
     return n_td, I
 
 
+def _kernel_name(base: str, docs: bool, cell_start: int, num_cells: int,
+                 k_total: int) -> str:
+    """The ``pallas_call`` name a profile shows: ``base``, ``_docs`` for
+    the doc-paged twin, and ``_h0``/``_h1`` where the call sweeps the
+    first or the second half-queue of a pipelined ring round."""
+    name = base + ("_docs" if docs else "")
+    if (cell_start, num_cells) == (0, k_total):
+        return name
+    return name + ("_h0" if cell_start == 0 else "_h1")
+
+
 def default_interpret() -> bool:
     """Pallas interpret-mode default: compiled on TPU, interpreted elsewhere.
 
@@ -313,7 +324,9 @@ def fused_sweep_cells(tok_doc: jax.Array, tok_wrd: jax.Array,
 
     kw = dict(alpha=float(alpha), beta=float(beta),
               beta_bar=float(beta_bar), n_blk=n_blk,
-              interpret=interpret, vmem_limit=vmem_limit)
+              interpret=interpret, vmem_limit=vmem_limit,
+              name=_kernel_name("fused_sweep_cells", docs, cell_start, k,
+                                k_total))
     kw["r_cap"] = cap
     if sparse:
         kw.update(topics=topics, counts=counts)
@@ -418,7 +431,9 @@ def fused_sweep_ragged(tok_doc: jax.Array, tok_wrd: jax.Array,
 
     kw = dict(alpha=float(alpha), beta=float(beta),
               beta_bar=float(beta_bar), n_blk=n_blk,
-              interpret=interpret, vmem_limit=vmem_limit)
+              interpret=interpret, vmem_limit=vmem_limit,
+              name=_kernel_name("fused_sweep_ragged", docs, cell_start, nc,
+                                k_total))
     kw["r_cap"] = cap
     if sparse:
         kw.update(topics=topics, counts=counts)

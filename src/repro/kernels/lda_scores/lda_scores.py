@@ -64,4 +64,5 @@ def lda_scores_pallas(n_td_rows: jax.Array, n_wt_rows: jax.Array,
             jax.ShapeDtypeStruct((n,), jnp.float32),
         ],
         interpret=interpret,
+        name="lda_scores",
     )(n_td_rows, n_wt_rows, n_t, u01)
