@@ -12,11 +12,14 @@ lives where the TPU can address it the way the chain needs:
   one scalar at a time, so their ``(n_blk,)`` tiles sit in **SMEM**, and
   ``z'`` is written back there;
 * the F+tree walk and path update are scalar loops, so the tree sits in
-  SMEM scratch — internal nodes ``1..T−1`` in one ``(T,)`` buffer, the
-  leaves in a ``(1, T)`` buffer — with a VMEM ``(1, T)`` mirror of the
-  leaves for the vector side of the draw.  A word-boundary rebuild writes
-  the q vector to the mirror, DMAs it to the SMEM leaves and sums the
-  internal nodes bottom-up;
+  one ``(2T,)`` SMEM buffer: the ``T − 1`` internal nodes **in order** —
+  the node over leaves ``[a, a + 2w)`` at position ``a + w``, the root at
+  ``T/2`` — then the leaves, with a VMEM ``(R, C)`` mirror of the leaves
+  for the vector side of the draw.  A word-boundary rebuild sums all
+  ``log2 T`` levels in vregs — per level one flat roll and add, each node
+  the single add ``left + right`` of its two children, and a roll and
+  select to its in-order position — and DMAs nodes and leaves to SMEM
+  at once, staged in a VMEM ``(2T,)`` buffer;
 * the doc-topic table ``n_td``, the word-topic block ``n_wt`` and ``n_t``
   live in VMEM for the whole call; per token the kernel loads and stores
   exactly one ``(1, T)`` row of each table by dynamic sublane index
@@ -80,7 +83,8 @@ so the first valid token of the stream must be a boundary (guaranteed by
 Chain exactness: every float op (q rebuild, path update, prefix sum, draw)
 is the same op on the same operands, in the same order, as the scan oracle
 ``ref.fused_sweep_ref``: the tree walk and path adds are the scalar ops of
-:mod:`repro.core.ftree`, the prefix sum is :func:`repro.core.prefix.prefix_sum`
+:mod:`repro.core.ftree`, each rebuilt node is the one add ``ftree.build``
+makes, the prefix sum is :func:`repro.core.prefix.prefix_sum`
 with a roll-based shift, and the r-vector is compacted by moving values
 only (:func:`repro.kernels.fused_sweep.rbucket.pack`).  Given identical
 uniforms the kernel reproduces the oracle's ``z``/counts/tree bit for bit,
@@ -112,8 +116,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ftree
-from repro.core.prefix import (prefix_sum_tiles, topic_iota, topic_tile,
-                               tpu_roll)
+from repro.core.prefix import (flat_roll, prefix_sum_tiles, topic_iota,
+                               topic_tile, tpu_roll)
 from repro.kernels.fused_sweep import rbucket
 
 N_BLK = 256  # tokens per grid program
@@ -121,58 +125,56 @@ N_BLK = 256  # tokens per grid program
 F32 = jnp.float32
 
 
+def _copy(src, dst, sem):
+    """One DMA, started and waited on."""
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
 # ---------------------------------------------------------------------------
-# The F+tree in SMEM: internal nodes f_int[1..T-1] (f_int[0] stays 0), leaves
-# f_leaf[(R, C) tile of t] — heap node T + t — plus a VMEM (R, C) mirror of
-# the leaves.
+# The F+tree in order, in one (2T,) SMEM buffer: the internal node over leaves
+# [a, a + 2w) at a + w (the root at T/2; 0 is never a node), leaf t at T + t.
+# A VMEM (R, C) mirror of the leaves serves the vector side of the draw; a
+# rebuild stages both halves in a VMEM (2T,) buffer and DMAs it to SMEM.
 # ---------------------------------------------------------------------------
 class _Tree:
-    def __init__(self, T, f_int, f_leaf, mirror, sem):
+    def __init__(self, T, smem, stage, mirror, sem):
         self.T, self.d = T, ftree.depth(T)
         self.R, self.C = topic_tile(T)
-        self.lc = self.C.bit_length() - 1
-        self.f_int, self.f_leaf, self.mirror, self.sem = (f_int, f_leaf,
-                                                          mirror, sem)
-
-    def _leaf(self, t):
-        """SMEM index of leaf ``t`` in the ``(R, C)`` leaf tile."""
-        return t >> self.lc, t & (self.C - 1)
+        self.smem, self.stage, self.mirror, self.sem = (smem, stage, mirror,
+                                                        sem)
 
     def zero(self):
-        def body(i, c):
-            self.f_int[i] = jnp.float32(0.0)
-            self.f_leaf[self._leaf(i)] = jnp.float32(0.0)
-            return c
-        jax.lax.fori_loop(0, self.T, body, 0)
+        self.stage[...] = jnp.zeros((2 * self.T,), F32)
         self.mirror[...] = jnp.zeros((self.R, self.C), F32)
+        _copy(self.stage, self.smem, self.sem)
 
     def build(self, q):
-        """``ftree.build``: leaves = q, node i = node 2i + node 2i+1."""
-        T, f_int, f_leaf = self.T, self.f_int, self.f_leaf
+        """``ftree.build`` level by level in vregs, then one DMA to SMEM.
+
+        After step ``k`` (children of half-width ``w = 2^k``), ``s[a]`` for
+        ``a ≡ 0 (mod 2w)`` is the single add ``left + right`` of the node
+        over ``[a, a + 2w)``; other lanes hold sums nothing reads.  Rolled
+        by ``w``, it lands on the node's in-order position ``a + w``."""
+        T = self.T
+        flat = topic_iota((self.R, self.C))
+        s, nodes = q, jnp.zeros_like(q)
+        for k in range(self.d):
+            w = 1 << k
+            s = s + flat_roll(s, -w, roll=tpu_roll)
+            nodes = jnp.where((flat & (2 * w - 1)) == w,
+                              flat_roll(s, w, roll=tpu_roll), nodes)
+        self.stage[pl.ds(0, T)] = nodes.reshape(T)
+        self.stage[pl.ds(T, T)] = q.reshape(T)
         self.mirror[...] = q
-        cp = pltpu.make_async_copy(self.mirror, f_leaf, self.sem)
-        cp.start()
-        cp.wait()
-
-        def from_leaves(j, c):
-            i = T - 1 - j                       # nodes T/2..T-1, descending
-            f_int[i] = (f_leaf[self._leaf(2 * i - T)]
-                        + f_leaf[self._leaf(2 * i + 1 - T)])
-            return c
-
-        def from_nodes(j, c):
-            i = T // 2 - 1 - j                  # nodes T/2-1..1, descending
-            f_int[i] = f_int[2 * i] + f_int[2 * i + 1]
-            return c
-
-        jax.lax.fori_loop(0, T // 2, from_leaves, 0)
-        jax.lax.fori_loop(0, T // 2 - 1, from_nodes, 0)
+        _copy(self.stage, self.smem, self.sem)
 
     def leaf(self, t):
-        return self.f_leaf[self._leaf(t)]
+        return self.smem[self.T + t]
 
     def total(self):
-        return self.f_int[1] if self.T > 1 else self.f_leaf[0, 0]
+        return self.smem[self.T // 2 if self.d else self.T]
 
     def set_leaf(self, t, value, flat):
         """``ftree.set_leaf``: add ``value − leaf`` along the leaf's path,
@@ -180,35 +182,38 @@ class _Tree:
         cur = self.leaf(t)
         delta = value - cur
         new = cur + delta
-        self.f_leaf[self._leaf(t)] = new
-        node = (t + self.T) >> 1
-        for _ in range(self.d):
-            self.f_int[node] = self.f_int[node] + delta
-            node = node >> 1
+        self.smem[self.T + t] = new
+        for k in range(self.d):                 # bottom-up, half-width 2^k
+            w = 1 << k
+            i = (t & ~(2 * w - 1)) | w
+            self.smem[i] = self.smem[i] + delta
         self.mirror[...] = jnp.where(flat == t, new, self.mirror[...])
 
     def sample(self, u01):
         """``ftree.sample``: the guarded top-down walk, one scalar per
-        level."""
-        T, f_int = self.T, self.f_int
+        level; ``m`` is the current node's in-order position."""
         u = u01 * self.total()
-        i = jnp.int32(1)
-        for lvl in range(self.d):
-            c = 2 * i
-            if lvl < self.d - 1:
-                left, right = f_int[c], f_int[c + 1]
-            else:
-                left, right = self.leaf(c - T), self.leaf(c + 1 - T)
+        m = jnp.int32(self.T // 2)
+        for k in reversed(range(self.d)):       # the node's half-width 2^k
+            h = (1 << k) >> 1
+            lo, hi, base = (m - h, m + h, 0) if k else (m - 1, m, self.T)
+            left, right = self.smem[base + lo], self.smem[base + hi]
             go_right = (u >= left) & (right > 0)
-            i = c + go_right.astype(jnp.int32)
+            m = jnp.where(go_right, hi, lo)
             u = jnp.where(go_right, u - left, u)
-        return i - T
+        return m
 
-    def flush(self, out_int, out_leaf):
-        for src, dst in ((self.f_int, out_int), (self.f_leaf, out_leaf)):
-            cp = pltpu.make_async_copy(src, dst, self.sem)
-            cp.start()
-            cp.wait()
+    def flush(self, out):
+        _copy(self.smem, out, self.sem)
+
+
+def _heap(tree, T):
+    """The in-order ``(2T,)`` tree as ``ftree``'s heap array ``F``: heap
+    level ``l``'s nodes sit at in-order positions ``w, 3w, 5w, …`` with
+    ``w = T / 2^(l+1)``."""
+    nodes, leaves = tree[:T], tree[T:]
+    levels = [nodes[T >> (l + 1)::T >> l] for l in range(ftree.depth(T))]
+    return jnp.concatenate([jnp.zeros((1,), F32), *levels, leaves])
 
 
 def _pick(tile, t):
@@ -364,7 +369,7 @@ def _split_refs(refs, n_prefetch, sparse):
     pre, rest = refs[:n_prefetch], refs[n_prefetch:]
     ins, rest = rest[:9], rest[9:]
     rb_in, rest = (rest[:2], rest[2:]) if sparse else ((), rest)
-    outs, rest = rest[:6], rest[6:]
+    outs, rest = rest[:5], rest[5:]
     rb_out, rest = (rest[:2], rest[2:]) if sparse else ((None, None), rest)
     scratch = rest
     return pre, ins, rb_in, outs, rb_out, scratch
@@ -387,10 +392,10 @@ def _make_kernel(T, n_blk, r_cap, sparse, alpha, beta, beta_bar, *,
             refs, n_prefetch, sparse)
         (tok_doc, tok_wrd, tok_valid, tok_bound, z_in, u,
          ntd_in_ref, nwt_in_ref, nt_in_ref) = ins
-        z_ref, ntd_ref, nwt_ref, nt_ref, fint_out, fleaf_out = outs
+        z_ref, ntd_ref, nwt_ref, nt_ref, tree_out = outs
         tpc_ref, cnt_ref = rb_out
-        f_int, f_leaf, mirror, sem = scratch[:4]
-        tree = _Tree(T, f_int, f_leaf, mirror, sem)
+        smem, stage, mirror, sem = scratch[:4]
+        tree = _Tree(T, smem, stage, mirror, sem)
         first, last, g, g_prev = grid_pos(pre)
 
         @pl.when(first)
@@ -430,9 +435,8 @@ def _make_kernel(T, n_blk, r_cap, sparse, alpha, beta, beta_bar, *,
         @pl.when(last)
         def _flush():
             if docs:
-                _slab_copy(slab, ntd_ref.at[pl.ds(g * doc_rows, doc_rows)],
-                           sem)
-            tree.flush(fint_out, fleaf_out)
+                _copy(slab, ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], sem)
+            tree.flush(tree_out)
 
     return kernel
 
@@ -441,12 +445,6 @@ def _make_kernel(T, n_blk, r_cap, sparse, alpha, beta, beta_bar, *,
 # Doc-tiled paging: n_td stays in ANY/HBM, one (doc_rows, T) slab is paged
 # through a VMEM scratch by explicit DMA (module docstring).
 # ---------------------------------------------------------------------------
-def _slab_copy(src, dst, sem):
-    cp = pltpu.make_async_copy(src, dst, sem)
-    cp.start()
-    cp.wait()
-
-
 def _doc_slab_page(doc_rows, g, g_prev, first, ntd_ref, slab, sem):
     """Slab prologue of one grid step: at the first step pull the first
     slab; at a slab switch, write the previous slab back and pull the
@@ -454,13 +452,12 @@ def _doc_slab_page(doc_rows, g, g_prev, first, ntd_ref, slab, sem):
     so every page-in reads the counts as of the last write-back."""
     @pl.when(first)
     def _init():
-        _slab_copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
+        _copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
 
     @pl.when(jnp.logical_not(first) & (g != g_prev))
     def _switch():
-        _slab_copy(slab, ntd_ref.at[pl.ds(g_prev * doc_rows, doc_rows)],
-                   sem)
-        _slab_copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
+        _copy(slab, ntd_ref.at[pl.ds(g_prev * doc_rows, doc_rows)], sem)
+        _copy(ntd_ref.at[pl.ds(g * doc_rows, doc_rows)], slab, sem)
 
 
 def _slab_accessors(slab, g, doc_rows):
@@ -499,7 +496,8 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
     ``nwt_index`` pages one block of a ``(k, J, T)`` word-topic queue per
     step (``None``: one whole ``(J, T)`` block); ``doc_rows > 0`` leaves
     ``n_td`` in HBM, aliased input to output, paged by slab.  The tree
-    sits in SMEM scratch, flushed to two HBM outputs.  Returns (z',
+    sits in SMEM scratch, flushed to one HBM output and laid out as a
+    heap after the call.  Returns (z',
     n_td', n_wt', n_t', F (2T,)) in the callers' shapes, plus the side
     tables in sparse mode.
     """
@@ -526,7 +524,7 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
                 pl.BlockSpec((1,) + n_wt.shape[1:],
                              lambda *g: (nwt_index(*g), 0, 0, 0)))
     rb_specs = [_whole(*topics.shape)] * 2 if sparse else []
-    scratch = [pltpu.SMEM((T,), F32), pltpu.SMEM(tile, F32),
+    scratch = [pltpu.SMEM((2 * T,), F32), pltpu.VMEM((2 * T,), F32),
                pltpu.VMEM(tile, F32), pltpu.SemaphoreType.DMA]
     if docs:
         scratch.append(pltpu.VMEM((doc_rows,) + tile, jnp.int32))
@@ -538,7 +536,7 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
                   *rb_specs],                            # side tables
         out_specs=[smem_tile,                            # z'
                    ntd_spec, nwt_spec, _whole(*tile),    # count tables
-                   any_spec, any_spec,                   # F+tree (HBM)
+                   any_spec,                             # F+tree (HBM)
                    *rb_specs],                           # side tables
         scratch_shapes=scratch,
     )
@@ -547,8 +545,7 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=[shape(toks[4]), shape(n_td), shape(n_wt), shape(n_t),
-                   jax.ShapeDtypeStruct((T,), F32),
-                   jax.ShapeDtypeStruct(tile, F32),
+                   jax.ShapeDtypeStruct((2 * T,), F32),
                    *([shape(topics)] * 2 if sparse else [])],
         compiler_params=(pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
                          if vmem_limit else None),
@@ -558,11 +555,10 @@ def _fused_call(prefetch, tokens, n_td, n_wt, n_t, topics, counts, *,
         name=name,
     )(*prefetch, *toks, n_td, n_wt, n_t,
       *((topics, counts) if sparse else ()))
-    z, n_td, n_wt, n_t, f_int, f_leaf = out[:6]
+    z, n_td, n_wt, n_t, tree = out[:5]
     flat = lambda a: a.reshape(a.shape[:-2] + (T,))
-    F = jnp.concatenate([f_int, flat(f_leaf)])
     return (z.reshape(tokens[4].shape), flat(n_td), flat(n_wt), flat(n_t),
-            F) + tuple(out[6:])
+            _heap(tree, T)) + tuple(out[5:])
 
 
 _STATIC = ("alpha", "beta", "beta_bar", "n_blk", "r_cap", "interpret",

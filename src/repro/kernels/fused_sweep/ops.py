@@ -49,22 +49,28 @@ def fused_vmem_bytes(I: int, J: int, T: int, n_blk: int = N_BLK,
     Pallas double-buffers it on the way in and on the way out: four
     copies.  ``r_cap > 0`` (sparse r-mode) adds the two ``(I, r_cap)`` i32
     side tables, each in+out whole-VMEM (doc-tiled twins included — the
-    tables are never slabbed).  ``n_t``, the leaf mirror and the
-    compiler's own rows come to sixteen topic rows.  Token tiles live in
-    SMEM (:func:`fused_smem_bytes`), so ``n_blk`` does not enter.
+    tables are never slabbed).  ``n_t``, the leaf mirror, the F+tree's
+    two-row DMA stage and the compiler's own rows come to eighteen topic
+    rows.  Token tiles live in SMEM (:func:`fused_smem_bytes`), so
+    ``n_blk`` does not enter.
     """
     del n_blk
     row = topic_row_bytes(T)
     ntd = doc_rows * row if doc_rows > 0 else 2 * I * row
     rb = 4 * 4 * I * r_cap if r_cap > 0 else 0
-    return ntd + rb + 4 * J * row + 16 * row
+    return ntd + rb + 4 * J * row + 18 * row
 
 
 def fused_smem_bytes(n_tiles: int, n_blk: int, T: int,
                      n_maps: int) -> int:
     """SMEM bytes of one compiled fused sweep call: ``n_maps``
     scalar-prefetched per-tile maps (cell and/or slab), the seven token
-    tiles double-buffered, and the ``2T`` F+tree."""
+    tiles double-buffered, and the F+tree — ``T − 1`` internal nodes and
+    ``T`` leaves, 8·T bytes: 8 KiB at T = 1024, 32 KiB at T = 4096, and
+    past the 960 KiB budget on its own only from T = 2^17.  The compiler
+    adds a few KiB of its own (map padding): at T = 4096 a call this
+    model puts 16 KiB under the core's 1 MiB compiles and one at 1 MiB
+    does not (``tests/test_tpu_compile.py``)."""
     return 4 * (n_maps * n_tiles + 2 * 7 * n_blk + 2 * T)
 
 

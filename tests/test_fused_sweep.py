@@ -43,6 +43,7 @@ class TestChainExactParity:
         (4, 13, 37, 9.0),
         (64, 21, 150, 15.0),
         (1024, 11, 97, 10.0),
+        (4096, 7, 61, 8.0),        # R = 32 rows: the tree's sublane rolls
     ])
     def test_fused_matches_scan_and_ref(self, T, num_docs, vocab, mean_len):
         corpus, state, doc_ids, word_ids, order, boundary = _setup(
@@ -77,6 +78,47 @@ class TestChainExactParity:
         np.testing.assert_array_equal(np.asarray(nwt_k), np.asarray(nwt_r))
         np.testing.assert_array_equal(np.asarray(nt_k), np.asarray(nt_r))
         np.testing.assert_array_equal(np.asarray(F_k), np.asarray(F_r))
+
+    @pytest.mark.parametrize("T", [16, 1024, 4096])
+    def test_tree_is_build_after_each_boundary(self, T):
+        """The F+tree a word boundary leaves is ``ftree.build`` of the
+        word's q vector, bit for bit: the stream is cut just after each
+        boundary, whose token is masked so that only the rebuild runs.
+        Boundaries fall several to one 256-token tile and on a tile edge
+        (token 256); β = 0 leaves every topic a word lacks at zero mass."""
+        from repro.core import ftree
+        n_blk, I, n_z = 256, 5, 6
+        cuts = (3, 7, 100, 256, 300)
+        runs = np.diff((0,) + cuts + (320,))
+        rng = np.random.default_rng(T)
+        wrd = np.repeat(np.arange(runs.size) % 4, runs).astype(np.int32)
+        bound = np.zeros(wrd.size, np.int32)
+        bound[[0, *cuts]] = 1
+        doc = rng.integers(0, I, wrd.size).astype(np.int32)
+        z = rng.integers(0, n_z, wrd.size).astype(np.int32)
+        u = rng.random(wrd.size).astype(np.float32)
+        n_td = np.zeros((I, T), np.int32)
+        n_wt = np.zeros((4, T), np.int32)
+        n_t = np.zeros((T,), np.int32)
+        np.add.at(n_td, (doc, z), 1)
+        np.add.at(n_wt, (wrd, z), 1)
+        np.add.at(n_t, z, 1)
+        kw = dict(alpha=50.0 / T, beta=0.0, beta_bar=1.0)
+        tables = (jnp.asarray(n_td), jnp.asarray(n_wt), jnp.asarray(n_t))
+        for c in cuts:
+            valid = (np.arange(c + 1) < c).astype(np.int32)
+            tok = [jnp.asarray(a[:c + 1]) for a in (doc, wrd)] + [
+                jnp.asarray(valid), jnp.asarray(bound[:c + 1])] + [
+                jnp.asarray(a[:c + 1]) for a in (z, u)]
+            _, _, nwt_k, nt_k, F_k = fused_sweep_tokens(*tok, *tables,
+                                                        n_blk=n_blk, **kw)
+            q = ((nwt_k[wrd[c]].astype(jnp.float32) + kw["beta"])
+                 / (nt_k.astype(jnp.float32) + kw["beta_bar"]))
+            assert int(jnp.sum(q == 0)) >= T - n_z
+            np.testing.assert_array_equal(np.asarray(F_k),
+                                          np.asarray(ftree.build(q)))
+            F_r = fused_sweep_ref(*tok, *tables, **kw)[4]
+            np.testing.assert_array_equal(np.asarray(F_k), np.asarray(F_r))
 
     def test_chain_crosses_tile_boundaries(self):
         """n_blk smaller than N: state must persist across grid programs."""

@@ -7,7 +7,8 @@ block sizes ``chip_smoke.py`` runs (NYTimes widths), so Mosaic refusals,
 VMEM/SMEM overruns and HBM overflow show up here at no chip cost.  The
 VMEM models in ``ops`` are checked against the compiler both ways: the
 limit the wrapper passes compiles, and a limit a tenth below the paged
-blocks' share does not.
+blocks' share does not.  The SMEM model is checked the same way at
+T=4096, where the F+tree is widest.
 """
 import os
 
@@ -21,7 +22,8 @@ from repro.kernels.fused_sweep import (fused_sweep_ragged,
                                        fused_vmem_bytes)
 from repro.kernels.fused_sweep.fused_sweep import (
     fused_sweep_ragged_docs_pallas, fused_sweep_ragged_pallas)
-from repro.kernels.fused_sweep.ops import VMEM_SCOPED_DEFAULT_BYTES
+from repro.kernels.fused_sweep.ops import (VMEM_SCOPED_DEFAULT_BYTES,
+                                           fused_smem_bytes)
 
 T = 1024
 VOCAB = 102_660
@@ -108,6 +110,36 @@ def test_vmem_model_is_tight(one_chip, shape):
                                 vmem_limit=int(0.9 * paged), **kw, **KW))
     with pytest.raises(Exception, match="vmem"):
         f.lower(*maps, *tok, *tables).compile()
+
+
+@pytest.mark.parametrize("slack,fits", [(16 * 2**10, True), (0, False)],
+                         ids=["fits", "over"])
+def test_smem_model_is_tight_at_t4096(one_chip, slack, fits):
+    """The doc-paged ragged sweep at T=4096 (a 32 KiB F+tree) with as many
+    tiles as its SMEM model leaves room for: a model ``slack`` under the
+    core's 1 MiB compiles, one at 1 MiB is refused.  So the compiler needs
+    no more than the model plus 16 KiB — half the tree — and the tree the
+    kernel allocates is the one ``ops.fused_smem_bytes`` counts."""
+    T4, I, J, k, doc_rows = 4096, 4096, 128, 8, 1024
+    smem = 2**20 - slack
+    base = fused_smem_bytes(0, TILE, T4, 2)
+    assert base == 4 * (14 * TILE + 2 * T4)
+    n_tiles = (smem - base) // 8
+    assert fused_smem_bytes(n_tiles, TILE, T4, 2) == smem
+    S = n_tiles * TILE
+    spec = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)
+    args = ([spec((n_tiles,))] * 2 + [spec((S,))] * 5
+            + [spec((S,), jnp.float32), spec((I, T4)), spec((k, J, T4)),
+               spec((T4,))])
+    f = jax.jit(lambda *a: fused_sweep_ragged_docs_pallas(
+        *a, n_blk=TILE, interpret=False, doc_rows=doc_rows,
+        vmem_limit=fused_vmem_bytes(I, J, T4, TILE, doc_rows) + 2**20,
+        alpha=50.0 / T4, beta=0.01, beta_bar=0.01 * VOCAB))
+    if fits:
+        assert "tpu_custom_call" in f.lower(*args).compile().as_text()
+    else:
+        with pytest.raises(Exception, match="smem"):
+            f.lower(*args).compile()
 
 
 def test_fold_in_compiles(one_chip):
