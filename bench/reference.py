@@ -111,16 +111,22 @@ def layout_mismatch(sch: Schedule, doc_ids, word_ids, V: int) -> int:
     return int(np.count_nonzero(a != b))
 
 
-def _recounts(sch: Schedule, z, shapes):
-    """Flat recounts of ``z`` into tables of ``shapes`` (``n_td``,
-    ``n_wt``, ``n_t``); longer than a table where a row falls outside."""
+def _flat(sch: Schedule, z, shapes):
+    """Each token's flat index into tables of ``shapes`` (``n_td``,
+    ``n_wt``, ``n_t``) under its topic in ``z``."""
     T = shapes[2][-1]
     zt = np.asarray(z).reshape(-1)[sch.pos].astype(np.int64)
     rows = (sch.w.astype(np.int64) * shapes[0][1] + sch.d_loc,
             sch.block.astype(np.int64) * shapes[1][1] + sch.j_loc,
             np.zeros_like(zt))
-    return [np.bincount(r * T + zt, minlength=int(np.prod(shape)))
-            for r, shape in zip(rows, shapes)]
+    return [r * T + zt for r in rows]
+
+
+def _recounts(sch: Schedule, z, shapes):
+    """Flat recounts of ``z`` into tables of ``shapes``; longer than a
+    table where a row falls outside."""
+    return [np.bincount(f, minlength=int(np.prod(shape)))
+            for f, shape in zip(_flat(sch, z, shapes), shapes)]
 
 
 def recount(sch: Schedule, z, shapes) -> list:
@@ -129,12 +135,34 @@ def recount(sch: Schedule, z, shapes) -> list:
             zip(_recounts(sch, z, shapes), shapes)]
 
 
+def _slab_mismatch(flat, table) -> int:
+    """Entries of ``table`` that differ from the count of the flat indices
+    ``flat``, one slab of its leading axis at a time, and 1 more where an
+    index falls past its end: :func:`_recounts`'s answer without a whole
+    recount of the table."""
+    table = np.asarray(table)
+    slabs = table.reshape(table.shape[0] if table.ndim > 1 else 1, -1)
+    m = slabs.shape[1]
+    inside = flat < table.size
+    flat = np.sort(flat[inside])
+    if flat.size and flat[0] < 0:
+        raise ValueError("a recount index is negative")
+    ends = np.searchsorted(flat, np.arange(1, slabs.shape[0] + 1) * m)
+    bad, lo = int(not inside.all()), 0
+    for i, hi in enumerate(ends):
+        c = np.bincount(flat[lo:hi] - i * m, minlength=m)
+        bad += int(np.count_nonzero(c != slabs[i]))
+        lo = hi
+    return bad
+
+
 def count_mismatch(sch: Schedule, z, n_td, n_wt, n_t) -> int:
-    """Entries of the three tables that differ from a recount of ``z``."""
-    tables = [np.asarray(t) for t in (n_td, n_wt, n_t)]
-    return sum(int(np.count_nonzero(c[:t.size] != t.reshape(-1)))
-               + int(c.size > t.size) for c, t in
-               zip(_recounts(sch, z, [t.shape for t in tables]), tables))
+    """Entries of the three tables that differ from a recount of ``z``,
+    each recounted one slab (a worker's ``n_td``, a block's ``n_wt``
+    page) at a time."""
+    tables = (n_td, n_wt, n_t)
+    return sum(_slab_mismatch(f, t) for f, t in
+               zip(_flat(sch, z, [np.shape(t) for t in tables]), tables))
 
 
 @functools.lru_cache(maxsize=None)

@@ -125,6 +125,40 @@ def test_token_altered_fails(broken):
     assert out["checks"]["count_mismatch"]["value"] > 0
 
 
+def flat_count_mismatch(sch, z, tables):
+    """The whole-table recount that ``count_mismatch`` replaced."""
+    import reference as ref
+    tables = [np.asarray(t) for t in tables]
+    return sum(int(np.count_nonzero(c[:t.size] != t.reshape(-1)))
+               + int(c.size > t.size) for c, t in
+               zip(ref._recounts(sch, z, [t.shape for t in tables]), tables))
+
+
+@pytest.mark.parametrize("fault", ["none", "entry", "row"])
+def test_blockwise_count_equals_flat(fault):
+    """The recount one slab at a time counts what a recount of the whole
+    table counts: with one entry altered, and with one token's row past
+    the end of the table."""
+    import reference as ref
+    rng = np.random.default_rng(3)
+    W, B, J, Dl, T, n = 2, 6, 5, 7, 8, 400
+    w = rng.integers(0, W, n)
+    sch = ref.Schedule(
+        pos=np.arange(n), w=w, r=w, s=np.arange(n), block=rng.integers(0, B, n),
+        doc=None, word=None, d_loc=rng.integers(0, Dl, n),
+        j_loc=rng.integers(0, J, n), uid=None, W=W, S=n)
+    z = rng.integers(0, T, n)
+    shapes = [(W, Dl, T), (B, J, T), (T,)]
+    tables = [t.astype(np.int32) for t in ref.recount(sch, z, shapes)]
+    if fault == "entry":
+        tables[1][3, 2, 5] += 1
+    elif fault == "row":
+        sch.j_loc[17] = J * B          # past the last block's page
+    got = ref.count_mismatch(sch, z, *tables)
+    assert got == flat_count_mismatch(sch, z, tables)
+    assert got == {"none": 0, "entry": 1, "row": 2}[fault]
+
+
 RING = """
 import json, sys
 sys.path[:0] = [{bench!r}, {src!r}]
